@@ -33,6 +33,7 @@ from benchmarks import (
     table2_local_computation,
     table3_cifar,
 )
+from repro.utils.compile_cache import use_compile_cache
 
 SUITES = {
     "table1": table1_client_fraction.main,
@@ -63,6 +64,7 @@ def main() -> None:
                     help="write all emitted rows as machine-readable JSON "
                          "(e.g. BENCH_pr4.json)")
     args = ap.parse_args()
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(SUITES)
     print("name,us_per_call,derived")
     failed = []

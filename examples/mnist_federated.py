@@ -8,7 +8,7 @@ Compares against FedSGD with --strategy fedsgd (which pins E=1, B=inf).
 The CLI assembles a declarative ``ExperimentSpec`` — print it with
 --print-spec, replay it elsewhere with ``ExperimentSpec.from_json`` —
 and constructs the engine via ``RoundEngine.from_spec``. Uses the
-synthetic MNIST stand-in (offline container; see DESIGN.md).
+synthetic MNIST stand-in (``repro.data.make_image_classification``).
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ from repro.core import FedAvgConfig, FedAvgM, make_eval_fn, RoundEngine
 from repro.core.strategies import FedAvg, FedSGD
 from repro.data import make_image_classification
 from repro.specs import CodecSpec, ExperimentSpec, ModelSpec, PartitionSpec
+from repro.utils.compile_cache import use_compile_cache
 
 
 def build_spec(args) -> ExperimentSpec:
@@ -86,6 +87,7 @@ def main():
     ap.add_argument("--print-spec", action="store_true",
                     help="dump the assembled ExperimentSpec JSON and exit")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = build_spec(args)
     if args.print_spec:

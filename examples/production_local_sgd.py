@@ -14,8 +14,10 @@ collective traffic):
 import sys
 
 from repro.launch.train import main
+from repro.utils.compile_cache import use_compile_cache
 
 if __name__ == "__main__":
+    use_compile_cache()
     argv = sys.argv[1:]
     extra = []
     if "--large" in argv:

@@ -14,6 +14,9 @@ import jax
 from repro.core import RoundEngine, make_eval_fn
 from repro.data import make_image_classification
 from repro.specs import PartitionSpec, get_spec
+from repro.utils.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # 1. The paper's non-IID MNIST 2NN cell, scaled to quickstart size: 50
 #    clients of ~2 classes each (pathological partition), C=20%/round.

@@ -22,6 +22,7 @@ from repro.core.strategies import FedSGD
 from repro.data.batching import windows_from_sequence
 from repro.data.synthetic import make_char_corpus
 from repro.specs import ModelSpec, PartitionSpec, get_spec
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--lr", type=float, default=10.0)
     ap.add_argument("--fedsgd", action="store_true", help="run the baseline instead")
     args = ap.parse_args()
+    use_compile_cache()
 
     train, test, V = make_char_corpus(args.roles, mean_chars_per_role=1500, seed=0)
     clients = [windows_from_sequence(t, args.unroll) for t in train]

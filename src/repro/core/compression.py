@@ -310,8 +310,8 @@ def topk_codec(keep_frac: float = 0.05) -> Codec:
     """Magnitude top-k (+int32 indices on the wire). Biased — the standard
     norm-preserving heuristic; k = max(floor(p * n), 1) is static.
 
-    Aggregation fuses into the Pallas ``sparse_aggregate`` scatter kernel:
-    the server scatter-accumulates the (idx, values) pairs straight into
+    Aggregation is one XLA scatter-add (``ops.sparse_fedavg_aggregate``):
+    the server accumulates the weighted (idx, values) pairs straight into
     the fp32 accumulator — the dense (m, N) per-client deltas of the
     generic vmap-decode path are never materialized."""
     if not 0.0 < keep_frac <= 1.0:
@@ -344,12 +344,11 @@ def topk_codec(keep_frac: float = 0.05) -> Codec:
         if axis_name is not None:
             return sharded_sparse_fedavg_aggregate(
                 payloads["idx"], payloads["values"], weights, n,
-                axis_name=axis_name, interpret=interpret,
-                accum_dtype=accum_dtype,
+                axis_name=axis_name, accum_dtype=accum_dtype,
             )
         return sparse_fedavg_aggregate(
             payloads["idx"], payloads["values"], weights, n,
-            interpret=interpret, accum_dtype=accum_dtype,
+            accum_dtype=accum_dtype,
         )
 
     return Codec(

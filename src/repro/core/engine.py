@@ -606,35 +606,34 @@ class RoundEngine:
             K=packed.num_clients, m=self._m, shards=self._shards, **shape_kw,
         )
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             # Everything replicates except the cohort: ids/valid split
             # m/D-per-device along the client axis; the psum-finished
             # aggregation makes the outputs replicated by construction
-            # (check_rep can't see through pallas_call, so it's off). The
+            # (check_vma can't see through pallas_call, so it's off). The
             # strategy state replicates like the params: strategy.apply
             # consumes the post-psum (already replicated) delta, so every
             # shard steps the identical outer state.
-            body = shard_map(
+            body = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P(), P(),
                           P(client_axis), P(client_axis), P(), P()),
                 out_specs=(P(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             # Supersteps scan INSIDE the shard_map: every input (pools,
             # params, strategy state, key, lr schedule) is replicated, each
             # shard slices its own m/D cohort chunk per round from the
             # replicated on-device draw, and the per-round psum keeps the
             # aggregation exactly as in the per-round path.
-            sbody = shard_map(
+            sbody = jax.shard_map(
                 sbody,
                 mesh=mesh,
                 in_specs=(P(),) * 8,
                 out_specs=(P(), P(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
         # Buffer donation: params and the strategy state are dead the
         # moment a round returns their successors (same shapes/dtypes), so
@@ -845,6 +844,52 @@ class RoundEngine:
         stays at 2; a ragged final chunk (n_rounds not a multiple of R)
         adds one scan-of-remainder executable."""
         return sum(f._cache_size() for f in self._executables)
+
+    def lower_round(self, rounds_per_step: int = 1):
+        """The executable ``run`` dispatches for one round
+        (``rounds_per_step=1``) or one R-round superstep, lowered for the
+        engine's current state without running it or advancing any stream.
+        Inspection only: ``.as_text()`` shows what the device runs — a
+        Pallas kernel that lowered for the chip appears as a
+        ``tpu_custom_call``, an interpreted one as plain XLA ops. Covers the
+        device-pool star and gossip lanes."""
+        if self.pool_kind == "streamed" or self.async_config is not None:
+            raise ValueError(
+                "lower_round covers the device-pool round and superstep "
+                "executables; the streamed and async lanes stage their "
+                "inputs per dispatch"
+            )
+        R = int(rounds_per_step)
+        with sanctioned_staging():
+            lr = jnp.float32(self.lr_at(self.round_idx))
+            lrs = jnp.full((R,), lr)
+            if self.topology is not None:
+                pool = (self._x, self._y, self._counts, self._spe,
+                        self._mix_idx, self._mix_w)
+                if R == 1:
+                    return self._gossip_round_jit.lower(
+                        self.params, *pool, self.sample_key, lr
+                    )
+                return self._gossip_superstep_jit.lower(
+                    self.params, self.sample_key, *pool, lrs
+                )
+            pool = (self._x, self._y, self._counts, self._spe)
+            if R > 1:
+                if self._rep is not None:
+                    lrs = jax.device_put(lrs, self._rep)
+                return self._superstep_jit.lower(
+                    self.params, self.outer_state, self.sample_key, *pool,
+                    lrs,
+                )
+            m_pad = self._m + (-self._m) % self._shards
+            inputs = (jnp.zeros((m_pad,), jnp.int32),
+                      jnp.ones((m_pad,), jnp.float32),
+                      jax.random.PRNGKey(0), lr)
+            if self._rep is not None:
+                inputs = jax.device_put(inputs, self._rep)
+            return self._round_jit.lower(
+                self.params, self.outer_state, *pool, *inputs
+            )
 
     def consensus_params(self) -> Any:
         """The node-mean parameter tree on the gossip lane (fp32 mean over

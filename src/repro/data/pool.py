@@ -73,20 +73,17 @@ def device_pool_budget() -> int:
     ``REPRO_DEVICE_POOL_BUDGET`` overrides (the tests' and benchmarks'
     lever); otherwise 60% of the backend's reported ``bytes_limit`` when it
     has one (TPU/GPU), else 2 GiB — the CPU backend reports no limit, and
-    an unbounded default would defeat the whole guard.
+    an unbounded default would defeat the whole guard. A device error
+    propagates: it is not a missing limit.
     """
     env = os.environ.get("REPRO_DEVICE_POOL_BUDGET", "")
     if env:
         return int(env)
-    try:  # lazy: importing this module must not touch a device
-        import jax
+    import jax  # lazy: importing this module must not touch a device
 
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return int(limit * 0.6)
-    except Exception:
-        pass
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit * 0.6)
     return 2 * 1024**3
 
 

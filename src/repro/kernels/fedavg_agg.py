@@ -33,6 +33,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+# Bytes a kernel's VMEM tiles may take: double-buffered input/output blocks
+# plus in-kernel temporaries. Half of the 16 MiB default scoped-VMEM limit
+# of a TPU v5e core, leaving the compiler room for its own scratch.
+VMEM_TILE_BUDGET = 8 << 20
+
+
+def round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def lane_block(per_lane_bytes: int, cap: int,
+               budget: int = VMEM_TILE_BUDGET) -> int:
+    """Largest power-of-two lane width in [128, ``cap``] whose tiles
+    (``per_lane_bytes`` per column) fit ``budget`` bytes. Powers of two
+    from 128 up are multiples of 128, which the TPU tiling rule needs for
+    any block that does not span its whole axis."""
+    b = 128
+    while b * 2 <= cap and b * 2 * per_lane_bytes <= budget:
+        b *= 2
+    return b
+
+
 def interpret_block_n(n: int) -> int:
     """Block width for INTERPRET mode: one block covering all ``n``
     columns (capped at 1M to bound the emulated tile).
@@ -46,20 +68,27 @@ def interpret_block_n(n: int) -> int:
     return min(max(n, 1), 1 << 20)
 
 
+def hardware_block_n(k: int) -> int:
+    """Block width on the chip for a K-client cohort: the widest
+    power-of-two column tile (at most 16384) whose double-buffered
+    ``(K, bn)`` input, fp32 working copy and ``(1, bn)`` output fit
+    :data:`VMEM_TILE_BUDGET`. K rows pad to the 8-row sublane tile; the
+    input is priced at 4 bytes per element whatever its storage dtype,
+    which also covers a bf16 input plus its fp32 cast. 16384 for K <= 16,
+    4096 at K = 100, 1024 at K = 512, 128 from K ~ 2700 on."""
+    kp = round_up(max(k, 1), 8)
+    return lane_block(4 * (3 * kp + 3 * 8), 16384)
+
+
 def _agg_kernel(w_ref, params_ref, o_ref, *, accum_dtype):
-    # params_ref: (K, block_n); w_ref: (K, 1) in SMEM-friendly layout.
-    # The weighted sum is phrased as a (K,) x (K, bn) contraction rather
-    # than broadcast-multiply + sum: same math and the same accum_dtype
-    # accumulator (preferred_element_type), but it hits the MXU on TPU and
-    # a single BLAS pass in interpret mode — ~13x faster there than the
-    # multi-pass elementwise emulation, which matters because interpret is
-    # the whole CPU CI hot path.
+    # params_ref: (K, bn); w_ref: (K, 1); o_ref: (1, bn). A weighted
+    # broadcast-multiply and a reduction over the client (sublane) axis,
+    # both in accum_dtype on the vector unit: exact fp32 arithmetic, and the
+    # kernel is bound by reading (K, bn) from HBM, not by these K FLOPs per
+    # column.
     p = params_ref[...].astype(accum_dtype)          # (K, bn)
     w = w_ref[...].astype(accum_dtype)               # (K, 1)
-    acc = jax.lax.dot_general(
-        w[:, 0], p, (((0,), (0,)), ((), ())),
-        preferred_element_type=accum_dtype,
-    )
+    acc = jnp.sum(p * w, axis=0, keepdims=True)      # (1, bn)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -81,11 +110,11 @@ def _aggregate_impl(stacked, weights, *, block_n, interpret, accum_dtype):
             pl.BlockSpec((K, 1), lambda i: (0, 0)),
             pl.BlockSpec((K, block_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * block_n,), stacked.dtype),
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, nb * block_n), stacked.dtype),
         interpret=interpret,
     )(w2, stacked)
-    return out[:N]
+    return out[0, :N]
 
 
 def fedavg_aggregate(
@@ -98,10 +127,11 @@ def fedavg_aggregate(
 ) -> jnp.ndarray:
     """Weighted sum over the client axis: (K, N), (K,) -> (N,).
 
-    ``block_n=None`` picks the backend policy: 16384 columns (VMEM-sized)
-    on hardware, one grid step (:func:`interpret_block_n`) in interpret
-    mode. Block choice never changes numerics — each output coordinate
-    reduces over K inside its own block.
+    ``block_n=None`` picks the backend policy: a VMEM-sized tile chosen
+    from K on hardware (:func:`hardware_block_n`), one grid step
+    (:func:`interpret_block_n`) in interpret mode. Block choice never
+    changes numerics — each output coordinate reduces over K inside its
+    own block.
 
     Contract: ``weights`` must already sum to 1 (normalize raw n_k in
     ``server_aggregate``, nowhere else). Checked eagerly when ``weights``
@@ -127,7 +157,10 @@ def fedavg_aggregate(
                 "tree_fedavg_aggregate instead — normalization lives there."
             )
     if block_n is None:
-        block_n = interpret_block_n(stacked.shape[1]) if interpret else 16384
+        block_n = (
+            interpret_block_n(stacked.shape[1]) if interpret
+            else hardware_block_n(stacked.shape[0])
+        )
     return _aggregate_impl(
         stacked,
         weights,

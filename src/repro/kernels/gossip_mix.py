@@ -20,9 +20,9 @@ against the full node axis of the column block in ``accum_dtype`` fp32
 ids accumulate — the one-hot rows add — matching the dense oracle
 :func:`gossip_mix_ref` (``W @ X``) that tests pin the kernel against.
 
-``interpret=True`` is the CPU-CI fallback; per the PR 4 convention the
-interpret block policy is ONE grid step (the emulated grid's per-step
-overhead dwarfs the block math at simulation sizes).
+``interpret=True`` is the CPU-CI fallback; the interpret block policy is
+ONE grid step (the emulated grid's per-step overhead dwarfs the block math
+at simulation sizes).
 """
 from __future__ import annotations
 
@@ -32,7 +32,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fedavg_agg import interpret_block_n
+from .fedavg_agg import (
+    VMEM_TILE_BUDGET,
+    interpret_block_n,
+    lane_block,
+    round_up,
+)
+
+
+def gossip_blocks(n: int, slots: int) -> tuple:
+    """Hardware ``(block_nodes, block_n)`` for ``n`` nodes of ``slots``
+    neighbor slots. Up to 128 output nodes per block, halved (in multiples
+    of 8) until the one-hot ``(block_nodes, slots, n)`` expansion and the
+    dense ``(block_nodes, n)`` row slice of W take at most half of
+    :data:`~repro.kernels.fedavg_agg.VMEM_TILE_BUDGET`; then the widest
+    column tile whose double-buffered full-node ``(n, bn)`` input, its
+    fp32 copy, the double-buffered output and the accumulator fit the
+    rest. Every input is priced at 4 bytes per element. At n = 100 ring
+    nodes this is (100, 2048)."""
+    lanes = round_up(n, 128)
+
+    def rows_bytes(bn_):
+        return bn_ * (round_up(slots, 8) + 8) * lanes * 4
+
+    block_nodes = min(n, 128)
+    while block_nodes > 8 and rows_bytes(block_nodes) > VMEM_TILE_BUDGET // 2:
+        block_nodes = max(8, round_up(block_nodes // 2, 8))
+    per_lane = 4 * (3 * round_up(n, 8) + 3 * round_up(block_nodes, 8))
+    return block_nodes, lane_block(
+        per_lane, 16384, VMEM_TILE_BUDGET - rows_bytes(block_nodes)
+    )
 
 
 def _mix_kernel(idx_ref, w_ref, x_ref, o_ref, *, accum_dtype):
@@ -48,13 +77,12 @@ def _mix_kernel(idx_ref, w_ref, x_ref, o_ref, *, accum_dtype):
     # W @ X semantics for a multigraph row.
     node_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_all), 2)
     onehot = (idx[:, :, None] == node_ids).astype(accum_dtype)
-    w_rows = jax.lax.dot_general(
-        w[:, None, :], onehot,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=accum_dtype,
-    )[:, 0, :]                                           # (bn, n_all)
+    w_rows = jnp.sum(w[:, :, None] * onehot, axis=1)     # (bn, n_all)
+    # Explicit fp32 contract precision, so the MXU cannot round the mixing
+    # weights or the parameters to bf16.
     acc = jax.lax.dot_general(
         w_rows, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=accum_dtype,
     )                                                    # (bn, bc)
     o_ref[...] = acc.astype(o_ref.dtype)
@@ -116,8 +144,8 @@ def gossip_mix(
     have weight 0 and contribute nothing; duplicate ids accumulate.
 
     ``block_nodes=None`` / ``block_n=None`` pick the backend policy:
-    (128 nodes, 16384 columns) VMEM-sized tiles on hardware, one grid step
-    in interpret mode (PR 4 convention). Block choice never changes
+    VMEM-sized tiles chosen from n on hardware (:func:`gossip_blocks`),
+    one grid step in interpret mode. Block choice never changes
     numerics — every output row contracts the full slot axis in
     ``accum_dtype`` inside its own block.
 
@@ -141,10 +169,11 @@ def gossip_mix(
             f"idx/weight must both be (n_nodes, max_slots) = ({n}, D); "
             f"got idx {idx.shape}, weight {weight.shape}"
         )
+    hw_nodes, hw_n = gossip_blocks(n, idx.shape[1])
     if block_nodes is None:
-        block_nodes = n if interpret else min(n, 128)
+        block_nodes = n if interpret else hw_nodes
     if block_n is None:
-        block_n = interpret_block_n(N) if interpret else 16384
+        block_n = interpret_block_n(N) if interpret else hw_n
     return _mix_impl(
         x, jnp.asarray(idx, jnp.int32), weight,
         block_nodes=block_nodes, block_n=block_n,

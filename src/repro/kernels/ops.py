@@ -20,7 +20,6 @@ from repro.kernels.quantized_agg import (
     packed_quantized_aggregate,
     quantized_aggregate,
 )
-from repro.kernels.sparse_agg import sparse_aggregate
 from repro.kernels.ssm_scan import ssm_scan
 from repro.utils.tree import tree_ravel_stacked, tree_unravel
 
@@ -202,37 +201,51 @@ def sharded_packed_quantized_fedavg_aggregate(words, lo, scale, weights, *,
     return num / den
 
 
-def sparse_fedavg_aggregate(idx, values, weights, n, *, interpret=False,
-                            accum_dtype=jnp.float32, block_clients=None):
-    """Weighted-average K sparse top-k client payloads into a dense (n,)
-    delta through the Pallas ``sparse_aggregate`` scatter kernel — the
-    server never materializes dense per-client deltas.
-
-    ``weights`` are RAW example counts n_k, normalized here (the kernel
-    asserts the normalized contract, mirroring ``tree_fedavg_aggregate``).
-    """
-    w = jnp.asarray(weights, jnp.float32)
-    w = w / jnp.sum(w)
-    return sparse_aggregate(
-        idx, values, w, n, block_clients=block_clients,
-        interpret=interpret, accum_dtype=accum_dtype,
+def _sparse_weighted_sum(idx, values, weights, n, accum_dtype):
+    """sum_k weights[k] * densify(idx[k], values[k]) as ONE XLA scatter-add
+    of the K*k weighted values into an (n,) ``accum_dtype`` accumulator —
+    O(K*k) work plus one dense output, never the (K, n) dense deltas.
+    Duplicate indices accumulate, matching ``ref.densify_ref``."""
+    if idx.ndim != 2 or idx.shape != values.shape:
+        raise ValueError(
+            f"idx and values must share a (K, k) shape; got idx "
+            f"{idx.shape}, values {values.shape}"
+        )
+    if weights.shape != (idx.shape[0],):
+        raise ValueError(
+            f"weights must be ({idx.shape[0]},), got {weights.shape}"
+        )
+    if n < 1:
+        raise ValueError(f"n must be >= 1, got {n}")
+    contrib = values.astype(accum_dtype) * weights.astype(accum_dtype)[:, None]
+    return jnp.zeros((n,), accum_dtype).at[idx.reshape(-1)].add(
+        contrib.reshape(-1)
     )
 
 
+def sparse_fedavg_aggregate(idx, values, weights, n, *,
+                            accum_dtype=jnp.float32):
+    """Weighted-average K sparse top-k client payloads into a dense (n,)
+    delta. The server never materializes dense per-client deltas: the
+    aggregate is an XLA scatter-add of the weighted (idx, value) pairs
+    (Mosaic has no scatter, so this lane has no Pallas kernel).
+
+    ``weights`` are RAW example counts n_k, normalized here, mirroring
+    ``tree_fedavg_aggregate``.
+    """
+    w = jnp.asarray(weights, jnp.float32)
+    return _sparse_weighted_sum(idx, values, w / jnp.sum(w), n, accum_dtype)
+
+
 def sharded_sparse_fedavg_aggregate(idx, values, weights, n, *, axis_name,
-                                    interpret=False,
-                                    accum_dtype=jnp.float32,
-                                    block_clients=None):
+                                    accum_dtype=jnp.float32):
     """Partial-sum mode of :func:`sparse_fedavg_aggregate` for cohort
     sharding: each shard scatter-accumulates its local (m/D, k) payload
     slice with UNnormalized weights, then one ``psum`` finishes the
     weighted sum and the weight total before the single division. Ghost
     (cohort-padding) clients carry weight 0 and vanish from both sums."""
     w = jnp.asarray(weights, jnp.float32)
-    partial = sparse_aggregate(
-        idx, values, w, n, block_clients=block_clients,
-        interpret=interpret, accum_dtype=accum_dtype,
-    )
+    partial = _sparse_weighted_sum(idx, values, w, n, accum_dtype)
     num = jax.lax.psum(partial, axis_name)
     den = jax.lax.psum(jnp.sum(w), axis_name)
     return num / den

@@ -2,19 +2,31 @@
 aggregation — the compressed-upload analogue of ``fedavg_agg``.
 
 Under the quantize codec (``core.compression.quantize_codec``) each client
-uploads its delta as uint8/uint16 codes plus per-chunk fp32 (lo, scale)
-range metadata. The naive server decodes every client to a dense fp32
-vector and then averages — materializing K x N fp32 (4-8x the wire size)
-in HBM just to immediately reduce it away. This kernel never does: each
-grid cell streams a (K, block) tile of CODES into VMEM, dequantizes and
-weighted-accumulates in ``accum_dtype`` (fp32 by default) registers, and
-writes only the (block,) averaged slice. Peak server memory for the
-aggregation stays at the compressed payload size + one dense output.
+uploads its delta as integer codes plus per-chunk fp32 (lo, scale) range
+metadata. The naive server decodes every client to a dense fp32 vector and
+then averages — materializing K x N fp32 (4-32x the wire size) in HBM just
+to immediately reduce it away. This kernel never does: each grid cell
+streams a (K, block) tile of code WORDS into VMEM, unpacks, dequantizes and
+weighted-accumulates in ``accum_dtype`` (fp32 by default), and writes only
+the block's averaged slice. Peak server memory for the aggregation stays at
+the compressed payload size + one dense output.
+
+One kernel serves every width. Sub-byte and odd widths arrive bit-packed
+(``utils.bitpack`` chunk framing, ``ppw = 32 // bits`` codes per uint32
+word); uint8 and uint16 codes are viewed as uint32 words of 4 or 2 codes,
+which is the same framing at ``bits = 8`` and ``16``.
+
+Layout on the chip: a block holds ``bc`` chunks as rows (sublanes) and a
+chunk's ``wpc`` words along the lanes, with each chunk's (lo, scale) as a
+one-lane column that broadcasts along its row. Code j of every word (one
+static shift + mask) accumulates in plane j; the planes go back into chunk
+order through exact 0/1 matmuls once per block, after the client loop.
 
 Layout contract (produced by ``quantize_codec``'s encode):
 
   codes:  (K, N_pad) uint8/uint16, N_pad a multiple of ``chunk``; code q in
           [0, levels] represents lo_c + q/levels * scale_c of its chunk c.
+          (Packed: (K, C * wpc) uint32 words.)
   lo:     (K, C) fp32, C = N_pad // chunk — per-chunk offset.
   scale:  (K, C) fp32 — per-chunk range (hi - lo; 0 for constant chunks,
           which dequantize exactly to lo).
@@ -26,8 +38,8 @@ Layout contract (produced by ``quantize_codec``'s encode):
 
 ``interpret=True`` runs the kernel body in the Pallas interpreter — the
 CPU test/CI fallback (Pallas does not lower on the CPU backend). On TPU
-leave the default and keep ``block_chunks`` such that
-(K+2) * block_chunks * chunk * 4 bytes fits VMEM.
+leave the default: ``block_chunks=None`` sizes the tiles from K
+(:func:`chunk_block`).
 """
 from __future__ import annotations
 
@@ -37,62 +49,151 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .fedavg_agg import VMEM_TILE_BUDGET
 
-def _qagg_kernel(w_ref, codes_ref, lo_ref, scale_ref, o_ref, *,
-                 chunk, levels, accum_dtype):
-    # codes_ref: (K, bc*chunk); lo/scale_ref: (K, bc); w_ref: (K, 1).
-    q = codes_ref[...].astype(accum_dtype)                     # (K, bn)
-    K, bn = q.shape
-    bc = bn // chunk
-    step = (scale_ref[...] / levels).astype(accum_dtype)       # (K, bc)
-    lo = lo_ref[...].astype(accum_dtype)                       # (K, bc)
-    deq = q.reshape(K, bc, chunk) * step[:, :, None] + lo[:, :, None]
-    w = w_ref[...].astype(accum_dtype)                         # (K, 1)
-    # Same contraction phrasing as fedavg_agg's kernel: (K,) x (K, bn)
-    # dot instead of broadcast-multiply + sum — identical math/accumulator,
-    # MXU-friendly on TPU and one BLAS pass under the interpreter.
-    acc = jax.lax.dot_general(
-        w[:, 0], deq.reshape(K, bn), (((0,), (0,)), ((), ())),
-        preferred_element_type=accum_dtype,
+
+def chunk_block(k: int, wpc: int, ppw: int, chunk: int, c: int) -> int:
+    """Hardware ``block_chunks`` for a K-client cohort: the most chunk rows
+    (a power of two from 8 to 128) whose tiles fit
+    :data:`~repro.kernels.fedavg_agg.VMEM_TILE_BUDGET`, or all ``c`` chunks
+    when they are fewer. A block's chunk rows sit on the sublane axis, so
+    any multiple of 8 meets the TPU tiling rule. Per chunk row each client
+    costs its double-buffered words and the two double-buffered one-lane
+    lo/scale columns (padded to 128 lanes); the ``ppw`` accumulator planes,
+    per-client temporaries and the double-buffered output row come once."""
+    lanes = -(-wpc // 128) * 128
+    per_row = (
+        k * 2 * (lanes + 2 * 128) * 4 + (ppw + 3) * lanes * 4 + 3 * chunk * 4
     )
+    bc = 8
+    while bc * 2 <= 128 and bc * 2 * per_row <= VMEM_TILE_BUDGET:
+        bc *= 2
+    return c if c <= bc else bc
+
+
+def _qagg_kernel(w_ref, words_ref, lo_ref, scale_ref, o_ref, *,
+                 bits, levels, accum_dtype):
+    # words_ref: (K, bc, wpc) uint32; lo/scale_ref: (K, bc, 1);
+    # w_ref: (K, 1, 1); o_ref: (bc, chunk). Plane j holds code j of every
+    # word (one static shift + mask), i.e. the chunk's codes t * ppw + j.
+    # One client at a time is unpacked, dequantized and weighted into the
+    # planes, so the working set does not grow with K.
+    K, bc, wpc = words_ref.shape
+    chunk = o_ref.shape[1]
+    ppw = 32 // bits
+    mask = jnp.uint32(2**bits - 1)
+
+    def client(k, planes):
+        words = words_ref[k]                                     # (bc, wpc)
+        step = (scale_ref[k] / levels).astype(accum_dtype)       # (bc, 1)
+        lo = lo_ref[k].astype(accum_dtype)                       # (bc, 1)
+        w = w_ref[k].astype(accum_dtype)                         # (1, 1)
+        out = []
+        for j, acc in enumerate(planes):
+            q = ((words >> jnp.uint32(j * bits)) & mask).astype(jnp.int32)
+            out.append(acc + w * (q.astype(accum_dtype) * step + lo))
+        return tuple(out)
+
+    zero = jnp.zeros((bc, wpc), accum_dtype)
+    planes = jax.lax.fori_loop(0, K, client, (zero,) * ppw)
+    # Interleave the planes back into chunk order on the MXU: plane j times
+    # the 0/1 matrix that sends word t to column t * ppw + j (columns past
+    # ``chunk`` are the frame's slack codes and are never formed). Every
+    # output column picks exactly one plane value, so splitting each value
+    # into three bf16 pieces (8 mantissa bits each, summing exactly to the
+    # fp32 value) makes three one-pass bf16 matmuls an exact shuffle.
+    t = jax.lax.broadcasted_iota(jnp.int32, (wpc, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (wpc, chunk), 1)
+    acc = jnp.zeros((bc, chunk), jnp.float32)
+    for j, plane in enumerate(planes):
+        sel = (col == t * ppw + j).astype(jnp.bfloat16)
+        rest = plane.astype(jnp.float32)
+        for _ in range(3):
+            piece = rest.astype(jnp.bfloat16)
+            rest = rest - piece.astype(jnp.float32)
+            acc = acc + jax.lax.dot_general(
+                piece, sel, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
     o_ref[...] = acc.astype(o_ref.dtype)
+
+
+def _pad_chunks(x, pad_c):
+    return jnp.pad(x, ((0, 0), (0, pad_c)) + ((0, 0),) * (x.ndim - 2))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("chunk", "levels", "block_chunks", "interpret",
+    static_argnames=("bits", "chunk", "levels", "block_chunks", "interpret",
                      "accum_dtype"),
 )
-def _qagg_impl(codes, lo, scale, weights, *, chunk, levels, block_chunks,
-               interpret, accum_dtype):
-    K, n_pad = codes.shape
-    C = n_pad // chunk
+def _qagg_impl(words, lo, scale, weights, *, bits, chunk, levels,
+               block_chunks, interpret, accum_dtype):
+    ppw = 32 // bits
+    wpc = -(-chunk // ppw)
+    K, n_words = words.shape
+    C = n_words // wpc
     bc = min(block_chunks, C)
     pad_c = (-C) % bc
-    if pad_c:
-        # Zero lo/scale dequantize the padded chunks to exactly 0, so the
-        # padded tail contributes nothing and is sliced off by the caller.
-        codes = jnp.pad(codes, ((0, 0), (0, pad_c * chunk)))
-        lo = jnp.pad(lo, ((0, 0), (0, pad_c)))
-        scale = jnp.pad(scale, ((0, 0), (0, pad_c)))
+    # Zero words decode to code 0; zero lo/scale dequantize that to
+    # exactly 0, so padded chunks contribute nothing.
+    words = _pad_chunks(words.reshape(K, C, wpc), pad_c)
+    lo = _pad_chunks(lo[:, :, None], pad_c)
+    scale = _pad_chunks(scale[:, :, None], pad_c)
     nb = (C + pad_c) // bc
-    bn = bc * chunk
-    w2 = weights.reshape(K, 1).astype(jnp.float32)
+    w3 = weights.reshape(K, 1, 1).astype(jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_qagg_kernel, chunk=chunk, levels=levels,
+        functools.partial(_qagg_kernel, bits=bits, levels=levels,
                           accum_dtype=accum_dtype),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((K, 1), lambda i: (0, 0)),
-            pl.BlockSpec((K, bn), lambda i: (0, i)),
-            pl.BlockSpec((K, bc), lambda i: (0, i)),
-            pl.BlockSpec((K, bc), lambda i: (0, i)),
+            pl.BlockSpec((K, 1, 1), lambda i: (0, 0, 0)),
+            pl.BlockSpec((K, bc, wpc), lambda i: (0, i, 0)),
+            pl.BlockSpec((K, bc, 1), lambda i: (0, i, 0)),
+            pl.BlockSpec((K, bc, 1), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * bn,), jnp.dtype(accum_dtype)),
+        out_specs=pl.BlockSpec((bc, chunk), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * bc, chunk),
+                                       jnp.dtype(accum_dtype)),
         interpret=interpret,
-    )(w2, codes, lo, scale)
-    return out[:n_pad]
+    )(w3, words, lo, scale)
+    return out.reshape(-1)[: C * chunk]
+
+
+def _check_ranges(name, k, c, lo, scale, weights):
+    if lo.shape != (k, c) or scale.shape != (k, c):
+        raise ValueError(
+            f"lo/scale must be (K, C)={(k, c)}; got lo {lo.shape}, "
+            f"scale {scale.shape}"
+        )
+    if not isinstance(weights, jax.core.Tracer):
+        s = float(jnp.sum(jnp.asarray(weights, jnp.float32)))
+        if abs(s - 1.0) > 1e-3:
+            raise ValueError(
+                f"{name} requires pre-normalized weights "
+                f"(sum==1); got sum={s:.6f}. Normalize raw counts in "
+                "core.compression.decode_aggregate, nowhere else."
+            )
+
+
+def _aggregate_words(words, lo, scale, weights, *, bits, chunk, levels,
+                     block_chunks, interpret, accum_dtype):
+    ppw = 32 // bits
+    K, C = lo.shape
+    if block_chunks is None:
+        # Interpret mode: one block covering all C chunks (capped at 1M
+        # emulated columns) — the emulated grid is an XLA while loop whose
+        # per-step overhead dwarfs the block math at simulation sizes (same
+        # policy as ``fedavg_agg.interpret_block_n``).
+        block_chunks = (
+            min(C, max(1, (1 << 20) // chunk)) if interpret
+            else chunk_block(K, -(-chunk // ppw), ppw, chunk, C)
+        )
+    return _qagg_impl(
+        words, lo, scale, weights,
+        bits=bits, chunk=chunk, levels=levels, block_chunks=block_chunks,
+        interpret=interpret, accum_dtype=jnp.dtype(accum_dtype),
+    )
 
 
 def quantized_aggregate(
@@ -111,42 +212,34 @@ def quantized_aggregate(
 
     Matches ``fedavg_aggregate(dequantize(codes, lo, scale), weights)`` to
     fp32 accumulation tolerance without ever materializing the (K, N_pad)
-    dense fp32 client deltas.
+    dense fp32 client deltas. The codes are bitcast to uint32 words of
+    ``4 // itemsize`` codes (little-endian, so code j of a word is its
+    j-th byte or half-word) and go through the packed kernel; ``chunk``
+    must be a multiple of that count.
 
-    ``block_chunks=None`` picks the backend policy: 32 chunks per VMEM tile
-    on hardware; in interpret mode one block covering all C chunks (capped
-    at 1M emulated columns) — the emulated grid is an XLA while loop whose
-    per-step overhead dwarfs the block math at simulation sizes, so a
-    single grid step beats the hardware default's C/32 steps by an order
-    of magnitude there (same policy as ``fedavg_agg.interpret_block_n``).
+    ``block_chunks=None`` picks the backend policy: :func:`chunk_block`'s
+    VMEM-sized tile on hardware, one grid step under the interpreter.
     """
     if codes.ndim != 2 or codes.shape[1] % chunk:
         raise ValueError(
             f"codes must be (K, C*chunk); got {codes.shape} with chunk={chunk}"
         )
-    want = (codes.shape[0], codes.shape[1] // chunk)
-    if lo.shape != want or scale.shape != want:
+    size = jnp.dtype(codes.dtype).itemsize
+    if size not in (1, 2) or chunk % (4 // size):
         raise ValueError(
-            f"lo/scale must be (K, C)={want}; got lo {lo.shape}, "
-            f"scale {scale.shape}"
+            f"codes must be uint8 or uint16 with chunk a multiple of "
+            f"{4 // max(size, 1)}; got {codes.dtype} with chunk={chunk}"
         )
-    if not isinstance(weights, jax.core.Tracer):
-        s = float(jnp.sum(jnp.asarray(weights, jnp.float32)))
-        if abs(s - 1.0) > 1e-3:
-            raise ValueError(
-                "quantized_aggregate requires pre-normalized weights "
-                f"(sum==1); got sum={s:.6f}. Normalize raw counts in "
-                "core.compression.decode_aggregate, nowhere else."
-            )
-    if block_chunks is None:
-        C = codes.shape[1] // chunk
-        block_chunks = (
-            min(C, max(1, (1 << 20) // chunk)) if interpret else 32
-        )
-    return _qagg_impl(
-        codes, lo, scale, weights,
-        chunk=chunk, levels=levels, block_chunks=block_chunks,
-        interpret=interpret, accum_dtype=jnp.dtype(accum_dtype),
+    K = codes.shape[0]
+    _check_ranges("quantized_aggregate", K, codes.shape[1] // chunk, lo,
+                  scale, weights)
+    words = jax.lax.bitcast_convert_type(
+        codes.reshape(K, -1, 4 // size), jnp.uint32
+    )
+    return _aggregate_words(
+        words, lo, scale, weights, bits=8 * size, chunk=chunk, levels=levels,
+        block_chunks=block_chunks, interpret=interpret,
+        accum_dtype=accum_dtype,
     )
 
 
@@ -160,74 +253,6 @@ def dequantize_ref(codes, lo, scale, *, chunk, levels):
     q = codes.astype(jnp.float32).reshape(K, C, chunk)
     x = q * (scale / levels)[:, :, None] + lo[:, :, None]
     return x.reshape(K, n_pad)
-
-
-# ---------------------------------------------------------------------------
-# packed sub-byte variant: the wire words ARE the kernel input
-# ---------------------------------------------------------------------------
-
-def _packed_qagg_kernel(w_ref, words_ref, lo_ref, scale_ref, o_ref, *,
-                        bits, chunk, levels, accum_dtype):
-    # words_ref: (K, bc*wpc) uint32; lo/scale_ref: (K, bc); w_ref: (K, 1).
-    words = words_ref[...]
-    K = words.shape[0]
-    ppw = 32 // bits
-    wpc = -(-chunk // ppw)
-    bc = words.shape[1] // wpc
-    # In-register unpack (bitpack.unpack_codes, phrased per tile): ppw
-    # static shift+mask lanes, then drop the per-chunk slack columns.
-    mask = jnp.uint32(2**bits - 1)
-    w3 = words.reshape(K, bc, wpc)
-    cols = [(w3 >> jnp.uint32(j * bits)) & mask for j in range(ppw)]
-    q = jnp.stack(cols, axis=-1).reshape(K, bc, wpc * ppw)[:, :, :chunk]
-    step = (scale_ref[...] / levels).astype(accum_dtype)       # (K, bc)
-    lo = lo_ref[...].astype(accum_dtype)                       # (K, bc)
-    deq = q.astype(accum_dtype) * step[:, :, None] + lo[:, :, None]
-    w = w_ref[...].astype(accum_dtype)                         # (K, 1)
-    acc = jax.lax.dot_general(
-        w[:, 0], deq.reshape(K, bc * chunk), (((0,), (0,)), ((), ())),
-        preferred_element_type=accum_dtype,
-    )
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("bits", "chunk", "levels", "block_chunks", "interpret",
-                     "accum_dtype"),
-)
-def _packed_qagg_impl(words, lo, scale, weights, *, bits, chunk, levels,
-                      block_chunks, interpret, accum_dtype):
-    ppw = 32 // bits
-    wpc = -(-chunk // ppw)
-    K, n_words = words.shape
-    C = n_words // wpc
-    bc = min(block_chunks, C)
-    pad_c = (-C) % bc
-    if pad_c:
-        # Zero words decode to code 0; zero lo/scale dequantize that to
-        # exactly 0, so padded chunks contribute nothing.
-        words = jnp.pad(words, ((0, 0), (0, pad_c * wpc)))
-        lo = jnp.pad(lo, ((0, 0), (0, pad_c)))
-        scale = jnp.pad(scale, ((0, 0), (0, pad_c)))
-    nb = (C + pad_c) // bc
-    w2 = weights.reshape(K, 1).astype(jnp.float32)
-    out = pl.pallas_call(
-        functools.partial(_packed_qagg_kernel, bits=bits, chunk=chunk,
-                          levels=levels, accum_dtype=accum_dtype),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((K, 1), lambda i: (0, 0)),
-            pl.BlockSpec((K, bc * wpc), lambda i: (0, i)),
-            pl.BlockSpec((K, bc), lambda i: (0, i)),
-            pl.BlockSpec((K, bc), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((bc * chunk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * bc * chunk,),
-                                       jnp.dtype(accum_dtype)),
-        interpret=interpret,
-    )(w2, words, lo, scale)
-    return out[: C * chunk]
 
 
 def packed_quantized_aggregate(
@@ -248,12 +273,12 @@ def packed_quantized_aggregate(
     The bit-packed twin of :func:`quantized_aggregate`: the input is the
     bit-packed uint32 wire form itself (``utils.bitpack`` chunk framing,
     ``wpc = ceil(chunk / (32 // bits))`` words per chunk), unpacked in the
-    kernel body — dense codes never exist outside VMEM registers. Any
-    width 1..15 works (the generic ``32 // bits`` codes-per-word unpack
-    covers the odd 9..15 widths the quantize codec now packs too); 16-bit
-    codes ship as exact uint16 stores through the unpacked kernel instead.
+    kernel body — dense codes never exist outside VMEM. Any width 1..15
+    works (the generic ``32 // bits`` codes-per-word unpack covers the odd
+    9..15 widths the quantize codec now packs too); 16-bit codes ship as
+    exact uint16 stores through :func:`quantized_aggregate` instead.
     Weights follow the same pre-normalized contract; block policy mirrors
-    ``quantized_aggregate`` (one grid step under the interpreter).
+    ``quantized_aggregate``.
     """
     if not 1 <= bits <= 15:
         raise ValueError(
@@ -265,29 +290,12 @@ def packed_quantized_aggregate(
             f"words must be (K, C*{wpc}) for chunk={chunk}, bits={bits}; "
             f"got {words.shape}"
         )
-    want = (words.shape[0], words.shape[1] // wpc)
-    if lo.shape != want or scale.shape != want:
-        raise ValueError(
-            f"lo/scale must be (K, C)={want}; got lo {lo.shape}, "
-            f"scale {scale.shape}"
-        )
-    if not isinstance(weights, jax.core.Tracer):
-        s = float(jnp.sum(jnp.asarray(weights, jnp.float32)))
-        if abs(s - 1.0) > 1e-3:
-            raise ValueError(
-                "packed_quantized_aggregate requires pre-normalized weights "
-                f"(sum==1); got sum={s:.6f}. Normalize raw counts in "
-                "core.compression.decode_aggregate, nowhere else."
-            )
-    if block_chunks is None:
-        C = words.shape[1] // wpc
-        block_chunks = (
-            min(C, max(1, (1 << 20) // chunk)) if interpret else 32
-        )
-    return _packed_qagg_impl(
-        words, lo, scale, weights,
-        bits=bits, chunk=chunk, levels=levels, block_chunks=block_chunks,
-        interpret=interpret, accum_dtype=jnp.dtype(accum_dtype),
+    _check_ranges("packed_quantized_aggregate", words.shape[0],
+                  words.shape[1] // wpc, lo, scale, weights)
+    return _aggregate_words(
+        words, lo, scale, weights, bits=bits, chunk=chunk, levels=levels,
+        block_chunks=block_chunks, interpret=interpret,
+        accum_dtype=accum_dtype,
     )
 
 

@@ -2,7 +2,8 @@
 
 Each ``*_ref`` mirrors its kernel's exact signature/semantics; tests sweep
 shapes and dtypes asserting allclose between kernel (interpret=True on CPU)
-and oracle.
+and oracle. ``densify_ref`` is the oracle of the top-k lane's XLA
+scatter-add aggregate, which has no Pallas kernel.
 """
 from __future__ import annotations
 
@@ -26,6 +27,16 @@ def fedavg_aggregate_ref(stacked, weights):
     return jnp.sum(
         stacked.astype(jnp.float32) * w[:, None], axis=0
     ).astype(stacked.dtype)
+
+
+def densify_ref(idx, vals, n: int):
+    """(K, k) sparse top-k payloads -> dense (K, n) fp32, the oracle of
+    ``ops.sparse_fedavg_aggregate``. Additive on duplicate indices (top-k
+    indices are unique per client, where add == set)."""
+    def one(i, v):
+        return jnp.zeros((n,), jnp.float32).at[i].add(v.astype(jnp.float32))
+
+    return jax.vmap(one)(idx, vals)
 
 
 def ssm_scan_ref(dt, Bm, Cm, x, A, h0):

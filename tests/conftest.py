@@ -1,14 +1,5 @@
-import sys
-
 import numpy as np
 import pytest
-
-try:  # pragma: no cover - exercised only where hypothesis is installed
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro.utils import hypothesis_stub
-
-    sys.modules["hypothesis"] = hypothesis_stub
 
 
 @pytest.fixture(scope="session", autouse=True)

@@ -82,6 +82,23 @@ def test_gossip_mix_kernel_is_lint_clean():
     assert report.parse_errors == [] and report.findings == []
 
 
+@pytest.mark.parametrize("wrap", ["jax.shard_map", "shard_map"])
+def test_shard_map_body_is_traced(wrap):
+    """A function handed to ``jax.shard_map`` (the engine's cohort-sharded
+    round) is a traced body, as under the older bare ``shard_map``."""
+    import ast
+
+    from repro.analysis.trace import TraceIndex
+
+    src = (
+        "def body(x):\n    return x\n\n"
+        f"f = {wrap}(body, mesh=m, in_specs=s, out_specs=s, check_vma=False)\n"
+    )
+    traced = TraceIndex(ast.parse(src)).traced
+    assert [fn.node.name for fn in traced] == ["body"]
+    assert traced[0].reason == wrap
+
+
 def test_suppression_comments_silence_findings():
     path = FIXTURES / "suppressed.py"
     src = path.read_text()

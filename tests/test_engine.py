@@ -306,6 +306,24 @@ def _tiny_engine(rng, cfg, **kw):
                        cfg, **kw)
 
 
+@pytest.mark.parametrize("R", [1, 3])
+def test_lower_round_is_inspection_only(rng, R):
+    """lower_round(R) lowers the executable run() dispatches without
+    running it: no stream advances, no compilation is cached, and the
+    following run matches a twin engine that never lowered."""
+    cfg = FedAvgConfig(C=0.5, E=1, B=8, lr=0.1, seed=0)
+    a = _tiny_engine(rng, cfg, device_sampling=True)
+    b = _tiny_engine(np.random.default_rng(0), cfg, device_sampling=True)
+    text = a.lower_round(R).as_text()
+    # interpret mode on the CPU: the kernel is plain XLA, no TPU call
+    assert "tpu_custom_call" not in text and len(text) > 0
+    assert a.num_compilations == 0 and a.round_idx == 0
+    ha, hb = a.run(R, rounds_per_step=R), b.run(R, rounds_per_step=R)
+    assert [r.train_loss for r in ha.records] == [
+        r.train_loss for r in hb.records
+    ]
+
+
 def test_lr_at_scalar_applies_decay(rng):
     eng = _tiny_engine(rng, FedAvgConfig(C=1.0, lr=0.2, lr_decay=0.5, seed=0))
     assert eng.lr_at(0) == pytest.approx(0.2)

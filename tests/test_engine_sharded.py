@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (
@@ -34,7 +33,7 @@ from repro.kernels.ops import (
     sharded_fedavg_aggregate,
     sharded_sparse_fedavg_aggregate,
 )
-from repro.kernels.sparse_agg import densify_ref
+from repro.kernels.ref import densify_ref
 from repro.launch.mesh import make_client_mesh
 from repro.models import mnist_2nn
 from repro.utils.tree import tree_weighted_mean
@@ -84,14 +83,14 @@ def test_sharded_fedavg_aggregate_matches_oracle(rng, K_per_shard):
         w[-1] = 0.0  # ghost row: must vanish from the average
     w = jnp.asarray(w)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda t, ww: sharded_fedavg_aggregate(
             t, ww, axis_name="clients", interpret=True
         ),
         mesh=mesh,
         in_specs=(P("clients"), P("clients")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     got = f(tree, w)
     want = tree_weighted_mean(tree, w)
@@ -101,7 +100,7 @@ def test_sharded_fedavg_aggregate_matches_oracle(rng, K_per_shard):
 
 @pytest.mark.parametrize("K_per_shard", [1, 3])
 def test_sharded_sparse_aggregate_matches_oracle(rng, K_per_shard):
-    """The sparse scatter kernel's partial-sum mode:
+    """The sparse scatter-add's partial-sum mode:
     shard_map(sharded_sparse_fedavg_aggregate) over the (K, k) top-k
     payloads == densify -> dense weighted mean, including zero-weight
     (ghost) rows."""
@@ -117,14 +116,14 @@ def test_sharded_sparse_aggregate_matches_oracle(rng, K_per_shard):
         w[-1] = 0.0  # ghost row: must vanish from the average
     w = jnp.asarray(w)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda i, v, ww: sharded_sparse_fedavg_aggregate(
-            i, v, ww, n, axis_name="clients", interpret=True
+            i, v, ww, n, axis_name="clients"
         ),
         mesh=mesh,
         in_specs=(P("clients"), P("clients"), P("clients")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     got = f(idx, vals, w)
     want = fedavg_aggregate(densify_ref(idx, vals, n), w / w.sum(),

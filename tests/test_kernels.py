@@ -8,6 +8,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
+from repro.kernels.ref import densify_ref
 from repro.kernels.ce_loss import fused_cross_entropy
 from repro.kernels.fedavg_agg import fedavg_aggregate
 from repro.kernels.flash_attention import flash_attention
@@ -17,7 +18,6 @@ from repro.kernels.quantized_agg import (
     quantized_aggregate,
     unpack_ref,
 )
-from repro.kernels.sparse_agg import densify_ref, sparse_aggregate
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels import ops
 from repro.utils.bitpack import pack_codes, words_per_chunk
@@ -86,6 +86,21 @@ def test_fedavg_aggregate_hypothesis(k, n, seed):
     np.testing.assert_allclose(out, ref.fedavg_aggregate_ref(st_, w), atol=1e-5)
 
 
+def test_fedavg_aggregate_hardware_block_policy_k512(rng):
+    """The chip's block policy at a 512-client cohort — several ragged
+    column blocks of hardware_block_n(512) — equals the oracle."""
+    from repro.kernels.fedavg_agg import hardware_block_n
+
+    K, bn = 512, hardware_block_n(512)
+    N = 2 * bn + 37
+    st_ = jnp.asarray(rng.normal(size=(K, N)).astype(np.float32))
+    w = jnp.asarray(rng.uniform(0.1, 5, K).astype(np.float32))
+    w = w / w.sum()
+    out = fedavg_aggregate(st_, w, block_n=bn, interpret=True)
+    np.testing.assert_allclose(out, ref.fedavg_aggregate_ref(st_, w),
+                               atol=1e-5)
+
+
 def test_tree_fedavg_aggregate_matches_server_line(rng):
     """Kernel path == Algorithm 1 server line on a real param pytree."""
     from repro.models import mnist_2nn
@@ -128,6 +143,36 @@ def test_quantized_aggregate_matches_dequantize_oracle(rng, K, N, chunk, bc):
     n_pad = codes.shape[1]
     assert out.shape == (n_pad,) and out.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantized_aggregate_hardware_block_policy(rng, bits):
+    """The chip's chunk_block policy at K=100 (16-chunk blocks, ragged
+    last block) equals dequantize -> fedavg_aggregate."""
+    from repro.kernels.quantized_agg import chunk_block
+
+    K, chunk = 100, 64
+    ppw = 32 // bits
+    C = 2 * chunk_block(K, chunk // ppw, ppw, chunk, 10**6) + 3
+    w = jnp.asarray(rng.uniform(0.1, 5.0, K).astype(np.float32))
+    w = w / w.sum()
+    bc = chunk_block(K, chunk // ppw, ppw, chunk, C)
+    if bits == 8:
+        codes, lo, scale = _quantized_payload(rng, K, C * chunk, chunk)
+        out = quantized_aggregate(codes, lo, scale, w, chunk=chunk,
+                                  levels=255, block_chunks=bc,
+                                  interpret=True)
+    else:
+        words, lo, scale, codes = _packed_payload(rng, K, C * chunk, chunk,
+                                                  bits)
+        out = packed_quantized_aggregate(words, lo, scale, w, bits=bits,
+                                         chunk=chunk, levels=2**bits - 1,
+                                         block_chunks=bc, interpret=True)
+    dense = dequantize_ref(codes, lo, scale, chunk=chunk,
+                           levels=2**bits - 1)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.fedavg_aggregate_ref(dense, w)),
+                               atol=1e-5)
 
 
 def test_quantized_aggregate_uint16_levels(rng):
@@ -221,7 +266,7 @@ def test_packed_quantized_aggregate_rejects_bad_inputs(rng):
 
 
 # ---------------------------------------------------------------------------
-# sparse top-k scatter-accumulate aggregation
+# sparse top-k scatter-accumulate aggregation (XLA scatter-add)
 # ---------------------------------------------------------------------------
 
 def _sparse_payload(rng, K, n, k, dtype=np.float32):
@@ -233,16 +278,15 @@ def _sparse_payload(rng, K, n, k, dtype=np.float32):
 
 
 @pytest.mark.parametrize("K", [1, 2, 17])
-@pytest.mark.parametrize("n,k,bc", [(37, 3, None), (513, 25, 2), (300, 15, 4)])
-def test_sparse_aggregate_matches_densify_oracle(rng, K, n, k, bc):
-    """Acceptance: the scatter-accumulate kernel == densify_ref ->
-    fedavg_aggregate for K in {1, 2, 17} and ragged n, including the
-    client-block-padding path (bc not dividing K)."""
+@pytest.mark.parametrize("n,k", [(37, 3), (513, 25), (300, 15)])
+def test_sparse_aggregate_matches_densify_oracle(rng, K, n, k):
+    """Acceptance: the scatter-add aggregate of RAW example counts ==
+    densify_ref -> weighted mean with normalized weights, for K in
+    {1, 2, 17} and ragged n."""
     idx, vals = _sparse_payload(rng, K, n, k)
     w = jnp.asarray(rng.uniform(0.1, 5.0, K).astype(np.float32))
-    w = w / w.sum()
-    out = sparse_aggregate(idx, vals, w, n, block_clients=bc, interpret=True)
-    want = fedavg_aggregate(densify_ref(idx, vals, n), w, interpret=True)
+    out = ops.sparse_fedavg_aggregate(idx, vals, w, n)
+    want = ref.fedavg_aggregate_ref(densify_ref(idx, vals, n), w / w.sum())
     assert out.shape == (n,) and out.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
@@ -252,8 +296,8 @@ def test_sparse_aggregate_bf16_values(rng):
     idx, vals = _sparse_payload(rng, 5, 200, 11)
     vals16 = vals.astype(jnp.bfloat16)
     w = jnp.full((5,), 0.2, jnp.float32)
-    out = sparse_aggregate(idx, vals16, w, 200, interpret=True)
-    want = fedavg_aggregate(densify_ref(idx, vals16, 200), w, interpret=True)
+    out = ops.sparse_fedavg_aggregate(idx, vals16, w, 200)
+    want = ref.fedavg_aggregate_ref(densify_ref(idx, vals16, 200), w)
     assert out.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-2)
 
@@ -263,35 +307,37 @@ def test_sparse_aggregate_zero_weight_client_vanishes(rng):
     contract the sharded lane relies on."""
     idx, vals = _sparse_payload(rng, 3, 100, 7)
     w = jnp.asarray([0.5, 0.5, 0.0])
-    out = sparse_aggregate(idx, vals, w, 100, interpret=True)
+    out = ops.sparse_fedavg_aggregate(idx, vals, w, 100)
     w2 = jnp.asarray([0.5, 0.5])
-    want = sparse_aggregate(idx[:2], vals[:2], w2, 100, interpret=True)
+    want = ops.sparse_fedavg_aggregate(idx[:2], vals[:2], w2, 100)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
 
 
 def test_sparse_aggregate_duplicate_indices_accumulate(rng):
-    """Duplicate indices WITHIN a client add — the kernel and densify_ref
-    agree on additive semantics (top-k never emits duplicates; add == set
-    there)."""
+    """Duplicate indices WITHIN a client add — the aggregate and
+    densify_ref agree on additive semantics (top-k never emits duplicates;
+    add == set there)."""
     idx = jnp.asarray([[2, 2, 5]], jnp.int32)
     vals = jnp.asarray([[1.0, 3.0, -2.0]], jnp.float32)
     w = jnp.ones((1,), jnp.float32)
-    out = sparse_aggregate(idx, vals, w, 8, interpret=True)
+    out = ops.sparse_fedavg_aggregate(idx, vals, w, 8)
     want = np.zeros(8, np.float32)
     want[2], want[5] = 4.0, -2.0
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(densify_ref(idx, vals, 8)[0]), atol=1e-6
+    )
 
 
 def test_sparse_aggregate_rejects_bad_inputs(rng):
     idx, vals = _sparse_payload(rng, 2, 64, 4)
-    with pytest.raises(ValueError, match="pre-normalized"):
-        sparse_aggregate(idx, vals, jnp.asarray([1.0, 2.0]), 64,
-                         interpret=True)
+    with pytest.raises(ValueError, match="n must be"):
+        ops.sparse_fedavg_aggregate(idx, vals, jnp.asarray([1.0, 2.0]), 0)
     with pytest.raises(ValueError, match="share a"):
-        sparse_aggregate(idx[:, :3], vals, jnp.asarray([0.5, 0.5]), 64,
-                         interpret=True)
+        ops.sparse_fedavg_aggregate(idx[:, :3], vals, jnp.asarray([0.5, 0.5]),
+                                    64)
     with pytest.raises(ValueError, match="weights must be"):
-        sparse_aggregate(idx, vals, jnp.asarray([1.0]), 64, interpret=True)
+        ops.sparse_fedavg_aggregate(idx, vals, jnp.asarray([1.0]), 64)
 
 
 # ---------------------------------------------------------------------------
@@ -366,6 +412,21 @@ def test_gossip_mix_matches_oracle_sweep(rng, kind, n, N, bn, bc):
     x = jnp.asarray(rng.normal(size=(n, N)).astype(np.float32))
     idx, w = _plan_arrays(kind, n)
     out = gossip_mix(x, idx, w, block_nodes=bn, block_n=bc, interpret=True)
+    np.testing.assert_allclose(out, gossip_mix_ref(x, idx, w), atol=1e-5)
+
+
+def test_gossip_mix_hardware_block_policy_n100(rng):
+    """The chip's gossip_blocks policy for a 100-node ring (all nodes in
+    one row block, several ragged column blocks) equals the dense oracle."""
+    from repro.kernels.gossip_mix import gossip_blocks
+
+    n = 100
+    idx, w = _plan_arrays("ring", n)
+    bnodes, bc = gossip_blocks(n, idx.shape[1])
+    N = 2 * bc + 5
+    x = jnp.asarray(rng.normal(size=(n, N)).astype(np.float32))
+    out = gossip_mix(x, idx, w, block_nodes=bnodes, block_n=bc,
+                     interpret=True)
     np.testing.assert_allclose(out, gossip_mix_ref(x, idx, w), atol=1e-5)
 
 

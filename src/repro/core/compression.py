@@ -526,30 +526,39 @@ def build_compressed_round_step(loss_fn, codec: Codec, *,
 
     def round_step(state, rb):
         params = state.params
-        upd = jax.vmap(
-            lambda b, msk: client_update(loss_fn, params, b, msk, rb.lr)
-        )
-        client_params, losses = upd(rb.data, rb.step_mask)
-        deltas = jax.tree.map(
-            lambda c, p: (c - p).astype(jnp.float32), client_params, params
-        )
-        flat, spec = tree_ravel_stacked(deltas)                  # (m, N)
-        m = flat.shape[0]
-        slot0 = 0 if axis_name is None else jax.lax.axis_index(axis_name) * m
-        keys = jax.vmap(lambda s: jax.random.fold_in(rb.key, s))(
-            slot0 + jnp.arange(m, dtype=jnp.int32)
-        )
-        payloads = jax.vmap(codec.encode)(keys, flat)
-        avg_flat = decode_aggregate(
-            codec, payloads, rb.client_weights, spec.total_size,
-            interpret=interpret, accum_dtype=accum_dtype, axis_name=axis_name,
-        )
-        avg_delta = tree_unravel(spec, avg_flat)
-        outer, new_params = strategy.apply(
-            state.outer_state, params, avg_delta
-        )
-        loss = masked_weighted_loss(losses, rb.step_mask, rb.client_weights,
-                                    axis_name=axis_name)
+        with jax.named_scope("fedavg.client_update"):
+            upd = jax.vmap(
+                lambda b, msk: client_update(loss_fn, params, b, msk, rb.lr)
+            )
+            client_params, losses = upd(rb.data, rb.step_mask)
+        with jax.named_scope("fedavg.encode"):
+            deltas = jax.tree.map(
+                lambda c, p: (c - p).astype(jnp.float32), client_params,
+                params,
+            )
+            flat, spec = tree_ravel_stacked(deltas)              # (m, N)
+            m = flat.shape[0]
+            slot0 = (0 if axis_name is None
+                     else jax.lax.axis_index(axis_name) * m)
+            keys = jax.vmap(lambda s: jax.random.fold_in(rb.key, s))(
+                slot0 + jnp.arange(m, dtype=jnp.int32)
+            )
+            payloads = jax.vmap(codec.encode)(keys, flat)
+        with jax.named_scope("fedavg.aggregate"):
+            avg_flat = decode_aggregate(
+                codec, payloads, rb.client_weights, spec.total_size,
+                interpret=interpret, accum_dtype=accum_dtype,
+                axis_name=axis_name,
+            )
+            avg_delta = tree_unravel(spec, avg_flat)
+        with jax.named_scope("fedavg.apply"):
+            outer, new_params = strategy.apply(
+                state.outer_state, params, avg_delta
+            )
+        with jax.named_scope("fedavg.client_update"):
+            loss = masked_weighted_loss(losses, rb.step_mask,
+                                        rb.client_weights,
+                                        axis_name=axis_name)
         return state._replace(params=new_params, outer_state=outer), {
             "loss": loss
         }

@@ -181,35 +181,41 @@ def build_simulation_round_step(
     interpret = default_interpret() if interpret is None else interpret
 
     def round_step(state: RoundState, rb: RoundBatch):
-        upd = jax.vmap(
-            lambda b, msk: client_update(loss_fn, state.params, b, msk, rb.lr)
-        )
-        client_params, losses = upd(rb.data, rb.step_mask)
-        loss = masked_weighted_loss(losses, rb.step_mask, rb.client_weights,
-                                    axis_name=axis_name)
+        with jax.named_scope("fedavg.client_update"):
+            upd = jax.vmap(
+                lambda b, msk: client_update(loss_fn, state.params, b, msk,
+                                             rb.lr)
+            )
+            client_params, losses = upd(rb.data, rb.step_mask)
+            loss = masked_weighted_loss(losses, rb.step_mask,
+                                        rb.client_weights,
+                                        axis_name=axis_name)
         if strategy is None:
-            new_params = server_aggregate(
-                client_params,
+            with jax.named_scope("fedavg.aggregate"):
+                new_params = server_aggregate(
+                    client_params,
+                    rb.client_weights,
+                    interpret=interpret,
+                    accum_dtype=accum_dtype,
+                    axis_name=axis_name,
+                )
+            return state._replace(params=new_params), {"loss": loss}
+        with jax.named_scope("fedavg.aggregate"):
+            deltas = jax.tree.map(
+                lambda c, p: (c - p).astype(jnp.float32),
+                client_params, state.params,
+            )
+            agg_delta = server_aggregate(
+                deltas,
                 rb.client_weights,
                 interpret=interpret,
                 accum_dtype=accum_dtype,
                 axis_name=axis_name,
             )
-            return state._replace(params=new_params), {"loss": loss}
-        deltas = jax.tree.map(
-            lambda c, p: (c - p).astype(jnp.float32),
-            client_params, state.params,
-        )
-        agg_delta = server_aggregate(
-            deltas,
-            rb.client_weights,
-            interpret=interpret,
-            accum_dtype=accum_dtype,
-            axis_name=axis_name,
-        )
-        outer, new_params = strategy.apply(
-            state.outer_state, state.params, agg_delta
-        )
+        with jax.named_scope("fedavg.apply"):
+            outer, new_params = strategy.apply(
+                state.outer_state, state.params, agg_delta
+            )
         return state._replace(params=new_params, outer_state=outer), {
             "loss": loss
         }
@@ -852,14 +858,21 @@ class RoundEngine:
         Inspection only: ``.as_text()`` shows what the device runs — a
         Pallas kernel that lowered for the chip appears as a
         ``tpu_custom_call``, an interpreted one as plain XLA ops. Covers the
-        device-pool star and gossip lanes."""
-        if self.pool_kind == "streamed" or self.async_config is not None:
+        star, streamed and gossip lanes; on the streamed lane one cohort (or
+        chunk) is staged for the lowering and the sampling stream rewound."""
+        if self.async_config is not None:
             raise ValueError(
-                "lower_round covers the device-pool round and superstep "
-                "executables; the streamed and async lanes stage their "
-                "inputs per dispatch"
+                "lower_round covers the round and superstep executables; the "
+                "async lane splits each round into a client and an apply "
+                "phase"
             )
         R = int(rounds_per_step)
+        if self.pool_kind == "streamed":
+            b = (self._prepare_round(self.round_idx) if R == 1
+                 else self._prepare_chunk(self.round_idx, R))
+            self.rng.bit_generator.state, self.sample_key = b["rng"]
+            fn = self._staged_round_jit if R == 1 else self._staged_superstep_jit
+            return fn.lower(self.params, self.outer_state, *b["dev"])
         with sanctioned_staging():
             lr = jnp.float32(self.lr_at(self.round_idx))
             lrs = jnp.full((R,), lr)
@@ -933,15 +946,20 @@ class RoundEngine:
             # the superstep scan advances its carry — that identity is what
             # makes superstep(R) == R x round() hold round for round
             # (tests/test_engine_superstep.py).
-            k_cohort, k_data, k_next = jax.random.split(self.sample_key, 3)
-            self.sample_key = k_next
-            with sanctioned_staging():
-                # The draw itself is device compute, but jax.random.uniform
-                # eagerly stages its weak-typed minval/maxval scalars, and
-                # under a mesh those commit to the NamedSharding — a real
-                # (tiny, bounded) per-round transfer we own here.
-                ids = sample_clients_device(k_cohort, self.num_clients, self._m)
-                ids, valid = pad_cohort_device(ids, self._shards)
+            with jax.named_scope("fedavg.sample"):
+                k_cohort, k_data, k_next = jax.random.split(
+                    self.sample_key, 3
+                )
+                self.sample_key = k_next
+                with sanctioned_staging():
+                    # The draw itself is device compute, but
+                    # jax.random.uniform eagerly stages its weak-typed
+                    # minval/maxval scalars, and under a mesh those commit
+                    # to the NamedSharding — a real (tiny, bounded)
+                    # per-round transfer we own here.
+                    ids = sample_clients_device(k_cohort, self.num_clients,
+                                                self._m)
+                    ids, valid = pad_cohort_device(ids, self._shards)
             return ids, valid, k_data, lr
         selected = sample_clients(self.rng, self.num_clients, self.cfg.C)
         # Pad to a multiple of the shard count with zero-weight ghosts
@@ -1003,14 +1021,17 @@ class RoundEngine:
         device-pool lanes trace (same keys in, same uint32 ops, so the
         realized cohorts and data keys are bit-identical)."""
         if self.device_sampling:
-            k_cohort, k_data, k_next = jax.random.split(self.sample_key, 3)
-            self.sample_key = k_next
-            with sanctioned_staging():
-                # Same bounded staging as _next_round_inputs: uniform's
-                # weak-typed minval/maxval scalars.
-                ids_dev = sample_clients_device(
-                    k_cohort, self.num_clients, self._m
+            with jax.named_scope("fedavg.sample"):
+                k_cohort, k_data, k_next = jax.random.split(
+                    self.sample_key, 3
                 )
+                self.sample_key = k_next
+                with sanctioned_staging():
+                    # Same bounded staging as _next_round_inputs: uniform's
+                    # weak-typed minval/maxval scalars.
+                    ids_dev = sample_clients_device(
+                        k_cohort, self.num_clients, self._m
+                    )
             return np.asarray(jax.device_get(ids_dev)), k_data
         ids = np.asarray(
             sample_clients(self.rng, self.num_clients, self.cfg.C)
@@ -1070,37 +1091,47 @@ class RoundEngine:
                 "rng": snap}
 
     def _round_streamed(self) -> Dict[str, float]:
-        b = (
-            self._take_prefetch("round", self.round_idx)
-            or self._prepare_round(self.round_idx)
-        )
+        with jax.profiler.TraceAnnotation("fedavg.prepare"):
+            b = (
+                self._take_prefetch("round", self.round_idx)
+                or self._prepare_round(self.round_idx)
+            )
         x, y, w, spe_k, key, lr = b["dev"]
-        self.params, self.outer_state, loss = self._staged_round_jit(
-            self.params, self.outer_state, x, y, w, spe_k, key, lr
-        )
+        with jax.profiler.TraceAnnotation("fedavg.dispatch"):
+            self.params, self.outer_state, loss = self._staged_round_jit(
+                self.params, self.outer_state, x, y, w, spe_k, key, lr
+            )
         self.round_idx += 1
         if self._prefetch_depth > 0:
             # Double buffer: the dispatch above returned without syncing,
             # so this shard read + staging overlaps the round's compute.
-            self._prefetched = self._prepare_round(self.round_idx)
+            with jax.profiler.TraceAnnotation("fedavg.prepare"):
+                self._prefetched = self._prepare_round(self.round_idx)
         return {"loss": loss}
 
     def _superstep_streamed(self, r: int) -> np.ndarray:
-        b = (
-            self._take_prefetch("chunk", self.round_idx, r)
-            or self._prepare_chunk(self.round_idx, r)
-        )
+        with jax.profiler.TraceAnnotation("fedavg.prepare"):
+            b = (
+                self._take_prefetch("chunk", self.round_idx, r)
+                or self._prepare_chunk(self.round_idx, r)
+            )
         xs, ys, ws, spes, keys, lrs = b["dev"]
-        self.params, self.outer_state, losses = self._staged_superstep_jit(
-            self.params, self.outer_state, xs, ys, ws, spes, keys, lrs
-        )
+        with jax.profiler.TraceAnnotation("fedavg.dispatch"):
+            self.params, self.outer_state, losses = (
+                self._staged_superstep_jit(
+                    self.params, self.outer_state, xs, ys, ws, spes, keys,
+                    lrs,
+                )
+            )
         self.round_idx += r
         if self._prefetch_depth > 0:
             # Stage the next chunk (same R — _run_supersteps' steady
             # state; a ragged final chunk just discards and rewinds)
             # while this one computes, then sync on this chunk's losses.
-            self._prefetched = self._prepare_chunk(self.round_idx, r)
-        return np.asarray(jax.device_get(losses))
+            with jax.profiler.TraceAnnotation("fedavg.prepare"):
+                self._prefetched = self._prepare_chunk(self.round_idx, r)
+        with jax.profiler.TraceAnnotation("fedavg.sync"):
+            return np.asarray(jax.device_get(losses))
 
     def round(self) -> Dict[str, float]:
         """One synchronous round; returns {'loss': ...} (plus
@@ -1109,11 +1140,13 @@ class RoundEngine:
             return self._round_gossip()
         if self.pool_kind == "streamed":
             return self._round_streamed()
-        ids, valid, key, lr = self._next_round_inputs()
-        self.params, self.outer_state, loss = self._round_jit(
-            self.params, self.outer_state, self._x, self._y, self._counts,
-            self._spe, ids, valid, key, lr,
-        )
+        with jax.profiler.TraceAnnotation("fedavg.prepare"):
+            ids, valid, key, lr = self._next_round_inputs()
+        with jax.profiler.TraceAnnotation("fedavg.dispatch"):
+            self.params, self.outer_state, loss = self._round_jit(
+                self.params, self.outer_state, self._x, self._y,
+                self._counts, self._spe, ids, valid, key, lr,
+            )
         self.round_idx += 1
         return {"loss": loss}
 
@@ -1171,22 +1204,25 @@ class RoundEngine:
         replays the key chain and stages all R cohorts up front)."""
         if self.pool_kind == "streamed":
             return self._superstep_streamed(r)
-        with sanctioned_staging():
+        with jax.profiler.TraceAnnotation("fedavg.prepare"), \
+                sanctioned_staging():
             lrs = jnp.asarray(
                 [self.lr_at(self.round_idx + i) for i in range(r)], jnp.float32
             )
             if self._rep is not None:
                 lrs = jax.device_put(lrs, self._rep)
-        self.params, self.outer_state, self.sample_key, losses = (
-            self._superstep_jit(
-                self.params, self.outer_state, self.sample_key, self._x,
-                self._y, self._counts, self._spe, lrs,
+        with jax.profiler.TraceAnnotation("fedavg.dispatch"):
+            self.params, self.outer_state, self.sample_key, losses = (
+                self._superstep_jit(
+                    self.params, self.outer_state, self.sample_key, self._x,
+                    self._y, self._counts, self._spe, lrs,
+                )
             )
-        )
         # Explicit D2H (device_get also syncs): the chunk boundary is a
         # sanctioned transfer, and explicitness keeps it legal under
         # transfer_guard("disallow") on guarded backends.
-        losses = np.asarray(jax.device_get(losses))
+        with jax.profiler.TraceAnnotation("fedavg.sync"):
+            losses = np.asarray(jax.device_get(losses))
         self.round_idx += r
         return losses
 
@@ -1254,7 +1290,10 @@ class RoundEngine:
         while done < n_rounds:
             r = min(R, n_rounds - done)
             t0 = time.perf_counter()
-            losses = self._superstep(r)  # blocks on the chunk's outputs
+            with jax.profiler.StepTraceAnnotation(
+                "fedavg.superstep", step_num=self.round_idx
+            ):
+                losses = self._superstep(r)  # blocks on the chunk's outputs
             chunk_s = time.perf_counter() - t0
             done += r
             for j in range(r):
@@ -1617,15 +1656,18 @@ def _engine_round(
     # Under shard_map ``ids``/``valid`` are this shard's (m/D,) cohort
     # slice; the shard's global slot offset keys all per-client randomness
     # so the sharded round replays the unsharded one exactly.
-    m_local = ids.shape[0]
-    slot0 = 0 if axis_name is None else jax.lax.axis_index(axis_name) * m_local
-    batch, mask, w = _assemble_batches(
-        px, py, counts, spe_arr, ids, key, E=E, spe=spe, B=B,
-        has_labels=has_labels, slot0=slot0,
-    )
-    # Ghost cohort-padding clients (valid == 0) keep a real row gather (id
-    # 0) but zero weight, so they vanish from the aggregate and the loss.
-    w = w * valid
+    with jax.named_scope("fedavg.assemble"):
+        m_local = ids.shape[0]
+        slot0 = (0 if axis_name is None
+                 else jax.lax.axis_index(axis_name) * m_local)
+        batch, mask, w = _assemble_batches(
+            px, py, counts, spe_arr, ids, key, E=E, spe=spe, B=B,
+            has_labels=has_labels, slot0=slot0,
+        )
+        # Ghost cohort-padding clients (valid == 0) keep a real row gather
+        # (id 0) but zero weight, so they vanish from the aggregate and the
+        # loss.
+        w = w * valid
     return _apply_round_step(
         loss_fn, params, outer, batch, mask, w, key, lr, codec=codec,
         strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
@@ -1655,7 +1697,8 @@ def _apply_round_step(
         )
         # Decorrelate the codec stream from the batch-permutation stream
         # (whose keys fold in global cohort slots above).
-        codec_key = jax.random.fold_in(key, 0x5EED)
+        with jax.named_scope("fedavg.encode"):
+            codec_key = jax.random.fold_in(key, 0x5EED)
     state, metrics = step(
         RoundState(params, outer_state=outer),
         RoundBatch(batch, mask, w, lr=lr, key=codec_key),
@@ -1673,9 +1716,10 @@ def _engine_round_staged(
     the on-device pool take — the population never touches device memory.
     No ``valid`` mask: the streamed lane is unsharded, so cohorts are never
     ghost-padded (and the device lane's ``w * 1.0`` is bitwise ``w``)."""
-    batch, mask, w = _assemble_cohort_batches(
-        cx, cy, w, spe_k, key, E=E, spe=spe, B=B, has_labels=has_labels,
-    )
+    with jax.named_scope("fedavg.assemble"):
+        batch, mask, w = _assemble_cohort_batches(
+            cx, cy, w, spe_k, key, E=E, spe=spe, B=B, has_labels=has_labels,
+        )
     return _apply_round_step(
         loss_fn, params, outer, batch, mask, w, key, lr, codec=codec,
         strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
@@ -1735,13 +1779,15 @@ def _engine_superstep(
 
     def one_round(carry, lr):
         p, o, k = carry
-        k_cohort, k_data, k_next = jax.random.split(k, 3)
-        ids = sample_clients_device(k_cohort, K, m)
-        ids, valid = pad_cohort_device(ids, shards)
-        if axis_name is not None:
-            d = jax.lax.axis_index(axis_name)
-            ids = jax.lax.dynamic_slice_in_dim(ids, d * m_local, m_local)
-            valid = jax.lax.dynamic_slice_in_dim(valid, d * m_local, m_local)
+        with jax.named_scope("fedavg.sample"):
+            k_cohort, k_data, k_next = jax.random.split(k, 3)
+            ids = sample_clients_device(k_cohort, K, m)
+            ids, valid = pad_cohort_device(ids, shards)
+            if axis_name is not None:
+                d = jax.lax.axis_index(axis_name)
+                ids = jax.lax.dynamic_slice_in_dim(ids, d * m_local, m_local)
+                valid = jax.lax.dynamic_slice_in_dim(valid, d * m_local,
+                                                     m_local)
         new_p, new_o, loss = _engine_round(
             loss_fn, p, o, px, py, counts, spe_arr, ids, valid, k_data, lr,
             E=E, spe=spe, B=B, has_labels=has_labels, codec=codec,

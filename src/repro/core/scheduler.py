@@ -131,17 +131,21 @@ class RoundScheduler:
         for i in range(n_rounds):
             t0 = time.perf_counter()
             sim_s = 0.0
-            if self.model is None:
-                metrics = eng.round()
-                # Honest per-round timing: stop the clock only after the
-                # round's outputs are synced — once dispatch is async, the
-                # un-synced time would be a dispatch latency, not a round
-                # time. device_get both syncs and keeps the D2H read
-                # explicit, so the loop stays legal under
-                # transfer_guard("disallow") on guarded backends.
-                loss = float(jax.device_get(metrics["loss"]))
-            else:
-                loss, sim_s = self._latency_round(lat_rng, speed)
+            with jax.profiler.StepTraceAnnotation(
+                "fedavg.round", step_num=eng.round_idx
+            ):
+                if self.model is None:
+                    metrics = eng.round()
+                    # Honest per-round timing: stop the clock only after
+                    # the round's outputs are synced — once dispatch is
+                    # async, the un-synced time would be a dispatch
+                    # latency, not a round time. device_get both syncs and
+                    # keeps the D2H read explicit, so the loop stays legal
+                    # under transfer_guard("disallow") on guarded backends.
+                    with jax.profiler.TraceAnnotation("fedavg.sync"):
+                        loss = float(jax.device_get(metrics["loss"]))
+                else:
+                    loss, sim_s = self._latency_round(lat_rng, speed)
             rec = RoundRecord(
                 round=eng.round_idx,
                 train_loss=loss,
@@ -175,27 +179,30 @@ class RoundScheduler:
         and charge the round the barrier time (slowest observed arrival).
         """
         eng = self.engine
-        ids, valid, key, lr = eng._next_round_inputs()
-        m = eng._m  # real clients lead the (possibly shard-padded) cohort
-        ids_np = np.asarray(ids)[:m]
-        t_obs, ok = self.model.draw(lat_rng, ids_np, speed)
-        sim_s = float(t_obs.max()) if len(t_obs) else 0.0
-        if not ok.all():
-            arrival = np.ones(np.asarray(valid).shape[0], np.float32)
-            arrival[:m] = ok.astype(np.float32)
-            valid = valid * jnp.asarray(arrival)
+        with jax.profiler.TraceAnnotation("fedavg.prepare"):
+            ids, valid, key, lr = eng._next_round_inputs()
+            m = eng._m  # real clients lead the (possibly shard-padded) cohort
+            ids_np = np.asarray(ids)[:m]
+            t_obs, ok = self.model.draw(lat_rng, ids_np, speed)
+            sim_s = float(t_obs.max()) if len(t_obs) else 0.0
+            if not ok.all():
+                arrival = np.ones(np.asarray(valid).shape[0], np.float32)
+                arrival[:m] = ok.astype(np.float32)
+                valid = valid * jnp.asarray(arrival)
         if not ok.any():
             # Every client failed: no update this round (an all-zero weight
             # vector would 0/0 in the normalizer). The round still happened
             # — it cost sim_s and produced nothing.
             eng.round_idx += 1
             return float("nan"), sim_s
-        eng.params, eng.outer_state, loss = eng._round_jit(
-            eng.params, eng.outer_state, eng._x, eng._y, eng._counts,
-            eng._spe, ids, valid, key, lr,
-        )
+        with jax.profiler.TraceAnnotation("fedavg.dispatch"):
+            eng.params, eng.outer_state, loss = eng._round_jit(
+                eng.params, eng.outer_state, eng._x, eng._y, eng._counts,
+                eng._spe, ids, valid, key, lr,
+            )
         eng.round_idx += 1
-        return float(jax.device_get(loss)), sim_s
+        with jax.profiler.TraceAnnotation("fedavg.sync"):
+            return float(jax.device_get(loss)), sim_s
 
     # ------------------------------------------------------------------
     # buffered-async schedule

@@ -4,8 +4,8 @@ One round, one executable::
 
       pack (once, host)          every round (device, jitted once)
     ┌──────────────────┐   ┌───────────────────────────────────────────┐
-    │ pack_clients     │   │ gather rows      x[ids] -> (m, n_pad, ..) │
-    │  (K, n_pad, ...) │──▶│ sample/permute   per-(client, epoch) perm │
+    │ pack_clients     │   │ sample/permute   per-(client, epoch) perm │
+    │  (K, n_pad, F)   │──▶│ gather rows      x[ids*n_pad + perm]      │
     │  counts, steps,  │   │ batch            -> (m, E*spe, B, ...)    │
     │  shape buckets   │   │ vmapped ClientUpdate (masked SGD scan)    │
     │                  │   │ Pallas fedavg_aggregate over (m, N)       │
@@ -526,12 +526,14 @@ class RoundEngine:
             self.pool = spool
             self.packed = spool.meta
             self._x = self._y = self._counts = self._spe = None
+            self._feature_shape = spool.feature_shape
             self._rep = None
             self._m = max(int(round(cfg.C * spool.num_clients)), 1)
             shape_kw = dict(
                 E=cfg.E,
                 spe=self.packed.max_real_steps_per_epoch,
                 B=self.packed.batch_size,
+                feature_shape=self._feature_shape,
                 has_labels=spool.has_labels,
                 codec=codec,
                 strategy=self.strategy,
@@ -564,7 +566,10 @@ class RoundEngine:
         # overrides the budget).
         packed = pack_clients(client_data, cfg.B,
                               max_bytes=device_pool_budget())
-        self._x = jnp.asarray(packed.x)
+        # Stored as (K, n_pad, F) rows (docs/engine.md "Pool layout"); the
+        # assembled batch gets its feature shape back.
+        self._feature_shape = tuple(int(d) for d in packed.x.shape[2:])
+        self._x = jnp.asarray(_as_rows(packed.x))
         self._y = jnp.asarray(packed.y) if packed.y is not None else None
         self._counts = jnp.asarray(packed.counts)
         self._spe = jnp.asarray(packed.steps_per_epoch)
@@ -599,6 +604,7 @@ class RoundEngine:
             E=cfg.E,
             spe=packed.max_real_steps_per_epoch,
             B=packed.batch_size,
+            feature_shape=self._feature_shape,
             has_labels=self._y is not None,
             codec=codec,
             strategy=self.strategy,
@@ -671,6 +677,7 @@ class RoundEngine:
                 E=cfg.E,
                 spe=packed.max_real_steps_per_epoch,
                 B=packed.batch_size,
+                feature_shape=self._feature_shape,
                 has_labels=self._y is not None,
                 interpret=self.interpret,
                 accum_dtype=jnp.dtype(accum_dtype),
@@ -732,7 +739,8 @@ class RoundEngine:
             cbody = partial(
                 _engine_client_phase, loss_fn,
                 E=cfg.E, spe=packed.max_real_steps_per_epoch,
-                B=packed.batch_size, has_labels=self._y is not None,
+                B=packed.batch_size, feature_shape=self._feature_shape,
+                has_labels=self._y is not None,
             )
             abody = partial(
                 _engine_apply_buffer, self.strategy, self._delta_spec,
@@ -1040,13 +1048,19 @@ class RoundEngine:
             key = jax.random.PRNGKey(int(self.rng.integers(2**31)))
         return ids, key
 
+    def _gather_cohort(self, ids):
+        """One cohort's host arrays from the streamed pool: x as
+        (m, n_pad, F) rows, the device pool's stored layout, so both
+        backends assemble from the same bytes."""
+        x, y = self.pool.gather(ids)
+        return (_as_rows(x), y, self.pool.counts[ids],
+                self.pool.steps_per_epoch[ids])
+
     def _prepare_round(self, for_round: int):
         """Draw, shard-read, and stage one round's cohort."""
         snap = self._rng_snapshot()
         ids, key = self._sample_ids_host()
-        x, y = self.pool.gather(ids)
-        w = self.pool.counts[ids]
-        spe_k = self.pool.steps_per_epoch[ids]
+        x, y, w, spe_k = self._gather_cohort(ids)
         with sanctioned_staging():
             dev = (
                 jax.device_put(x),
@@ -1069,11 +1083,11 @@ class RoundEngine:
         xs, ys, ws, spes, keys = [], [], [], [], []
         for i in range(r):
             ids, key = self._sample_ids_host()
-            x, y = self.pool.gather(ids)
+            x, y, w, spe_k = self._gather_cohort(ids)
             xs.append(x)
             ys.append(y)
-            ws.append(self.pool.counts[ids])
-            spes.append(self.pool.steps_per_epoch[ids])
+            ws.append(w)
+            spes.append(spe_k)
             keys.append(key)
         lrs = np.asarray(
             [self.lr_at(for_round + i) for i in range(r)], np.float32
@@ -1556,51 +1570,65 @@ class RoundEngine:
         does — for equivalence tests and the legacy-vs-engine benchmark.
         Always the UNSHARDED view (global slot 0 onward)."""
         if self.pool_kind == "streamed":
-            ids = np.asarray(ids)
-            x, y = self.pool.gather(ids)
+            x, y, w, spe_k = self._gather_cohort(np.asarray(ids))
             with sanctioned_staging():
                 return _assemble_cohort_batches(
                     jnp.asarray(x),
                     jnp.asarray(y) if y is not None else None,
-                    jnp.asarray(self.pool.counts[ids]),
-                    jnp.asarray(self.pool.steps_per_epoch[ids]),
-                    key,
+                    jnp.arange(len(x), dtype=jnp.int32),
+                    jnp.asarray(w), jnp.asarray(spe_k), key,
                     E=self.cfg.E, spe=self.packed.max_real_steps_per_epoch,
-                    B=self.packed.batch_size, has_labels=y is not None,
+                    B=self.packed.batch_size,
+                    feature_shape=self._feature_shape,
+                    has_labels=y is not None,
                 )
         return _assemble_batches(
             self._x, self._y, self._counts, self._spe,
             jnp.asarray(ids, jnp.int32), key,
             E=self.cfg.E, spe=self.packed.max_real_steps_per_epoch,
-            B=self.packed.batch_size, has_labels=self._y is not None,
+            B=self.packed.batch_size, feature_shape=self._feature_shape,
+            has_labels=self._y is not None,
         )
+
+
+def _as_rows(x):
+    """(K, n_pad, *feature_shape) -> (K, n_pad, F): each example one row.
+    On the TPU an example stored as, say, a 28x28x1 tile puts the example
+    index in the lanes, and the per-client gather then moves it element by
+    element; as a row it is one lane-dense copy. Rank-1 features are rows
+    already. A view of a contiguous numpy array."""
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 # The round body lives at module level so the jit cache key is stable and
 # introspectable; everything shape-like is a closed-over Python int.
 
 def _assemble_batches(px, py, counts, spe_arr, ids, key, *, E, spe, B,
-                      has_labels, slot0=0):
-    """Device-pool batch assembly: on-device pool gather, then the shared
-    cohort half below. The streamed lane skips the gather (its cohorts
-    arrive pre-staged) and enters at :func:`_assemble_cohort_batches` — the
-    seam that makes the two backends bit-for-bit identical: a gather copies
-    rows exactly, so from the cohort on both lanes run the same ops on the
-    same bytes."""
-    xs = jnp.take(px, ids, axis=0)                       # (m, n_pad, ...)
-    ys = jnp.take(py, ids, axis=0) if has_labels else None
+                      feature_shape, has_labels, slot0=0):
+    """Device-pool batch assembly: the cohort's weights and step counts,
+    then the shared half below on the whole (K, n_pad, F) row pool
+    (:func:`_as_rows`). The streamed lane stages its cohort as such a pool
+    of m clients and enters at :func:`_assemble_cohort_batches` with ids
+    0..m-1 — the seam that makes the two backends bit-for-bit identical: a
+    gather copies rows exactly, so both lanes run the same ops on the same
+    bytes."""
     w = jnp.take(counts, ids)                            # (m,)
     spe_k = jnp.take(spe_arr, ids)                       # (m,) real steps/epoch
     return _assemble_cohort_batches(
-        xs, ys, w, spe_k, key, E=E, spe=spe, B=B, has_labels=has_labels,
-        slot0=slot0,
+        px, py, ids, w, spe_k, key, E=E, spe=spe, B=B,
+        feature_shape=feature_shape, has_labels=has_labels, slot0=slot0,
     )
 
 
-def _assemble_cohort_batches(xs, ys, w, spe_k, key, *, E, spe, B,
-                             has_labels, slot0=0):
-    m = xs.shape[0]
-    n_pad = xs.shape[1]
+def _assemble_cohort_batches(px, py, ids, w, spe_k, key, *, E, spe, B,
+                             feature_shape, has_labels, slot0=0):
+    """The cohort half of assembly: clients ``ids`` of a (K, n_pad, F) row
+    pool in, per-(client, epoch) permuted minibatches out as (m, E*spe, B,
+    *feature_shape). One gather of whole rows by pool row index
+    ``ids * n_pad + perm``; the model's input shape is restored only on the
+    gathered batch."""
+    m = ids.shape[0]
+    n_pad = px.shape[1]
     # One fresh draw order per (client, epoch), the on-device analogue of
     # per-epoch reshuffling in ClientUpdate. Keying the sort by u + 2*[row
     # is padding] puts a uniform permutation of the client's n_k REAL rows
@@ -1633,13 +1661,15 @@ def _assemble_cohort_batches(xs, ys, w, spe_k, key, *, E, spe, B,
 
     perm = jax.vmap(jax.vmap(draw_order, in_axes=(0, None)))(keys, n_real)
     perm = perm[:, :, : spe * B].reshape(m, E * spe * B)
-    gather = jax.vmap(lambda rows, p: jnp.take(rows, p, axis=0))
-    bx = gather(xs, perm).reshape((m, E * spe, B) + xs.shape[2:])
-    by = (
-        gather(ys, perm).reshape((m, E * spe, B) + ys.shape[2:])
-        if has_labels
-        else None
-    )
+    rows = (ids[:, None] * n_pad + perm).reshape(-1)
+
+    def gather(pool):
+        flat = pool.reshape((-1,) + pool.shape[2:])      # (K*n_pad, ...)
+        return jnp.take(flat, rows, axis=0).reshape(
+            (m, E * spe, B) + pool.shape[2:])
+
+    bx = gather(px).reshape((m, E * spe, B) + feature_shape)
+    by = gather(py) if has_labels else None
     # Step s is real iff its epoch-local index is below the client's own
     # steps_per_epoch; padded steps are masked no-ops in client_update.
     step_in_epoch = jnp.arange(E * spe, dtype=jnp.int32) % spe
@@ -1650,8 +1680,8 @@ def _assemble_cohort_batches(xs, ys, w, spe_k, key, *, E, spe, B,
 
 def _engine_round(
     loss_fn, params, outer, px, py, counts, spe_arr, ids, valid, key, lr,
-    *, E, spe, B, has_labels, codec, strategy, interpret, accum_dtype,
-    axis_name=None,
+    *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
+    accum_dtype, axis_name=None,
 ):
     # Under shard_map ``ids``/``valid`` are this shard's (m/D,) cohort
     # slice; the shard's global slot offset keys all per-client randomness
@@ -1662,7 +1692,7 @@ def _engine_round(
                  else jax.lax.axis_index(axis_name) * m_local)
         batch, mask, w = _assemble_batches(
             px, py, counts, spe_arr, ids, key, E=E, spe=spe, B=B,
-            has_labels=has_labels, slot0=slot0,
+            feature_shape=feature_shape, has_labels=has_labels, slot0=slot0,
         )
         # Ghost cohort-padding clients (valid == 0) keep a real row gather
         # (id 0) but zero weight, so they vanish from the aggregate and the
@@ -1708,17 +1738,20 @@ def _apply_round_step(
 
 def _engine_round_staged(
     loss_fn, params, outer, cx, cy, w, spe_k, key, lr,
-    *, E, spe, B, has_labels, codec, strategy, interpret, accum_dtype,
+    *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
+    accum_dtype,
 ):
     """The streamed-pool round body: identical to :func:`_engine_round`
-    from the cohort on, but the (m, n_pad, ...) rows arrive pre-gathered
-    (host shard reads staged through ``sanctioned_staging``) instead of via
-    the on-device pool take — the population never touches device memory.
+    but the cohort's (m, n_pad, F) rows arrive pre-staged (host shard reads,
+    ``sanctioned_staging``) and assembly gathers from them as from a pool of
+    m clients — the population never touches device memory.
     No ``valid`` mask: the streamed lane is unsharded, so cohorts are never
     ghost-padded (and the device lane's ``w * 1.0`` is bitwise ``w``)."""
     with jax.named_scope("fedavg.assemble"):
         batch, mask, w = _assemble_cohort_batches(
-            cx, cy, w, spe_k, key, E=E, spe=spe, B=B, has_labels=has_labels,
+            cx, cy, jnp.arange(cx.shape[0], dtype=jnp.int32), w, spe_k, key,
+            E=E, spe=spe, B=B, feature_shape=feature_shape,
+            has_labels=has_labels,
         )
     return _apply_round_step(
         loss_fn, params, outer, batch, mask, w, key, lr, codec=codec,
@@ -1728,7 +1761,8 @@ def _engine_round_staged(
 
 def _engine_superstep_staged(
     loss_fn, params, outer, cxs, cys, ws, spes, keys, lrs,
-    *, E, spe, B, has_labels, codec, strategy, interpret, accum_dtype,
+    *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
+    accum_dtype,
 ):
     """The streamed twin of :func:`_engine_superstep`: R pre-staged cohorts
     scanned in one donated executable. The cohort draw already happened on
@@ -1743,7 +1777,8 @@ def _engine_superstep_staged(
         cx, cy, w, spe_k, key, lr = inp
         new_p, new_o, loss = _engine_round_staged(
             loss_fn, p, o, cx, cy, w, spe_k, key, lr,
-            E=E, spe=spe, B=B, has_labels=has_labels, codec=codec,
+            E=E, spe=spe, B=B, feature_shape=feature_shape,
+            has_labels=has_labels, codec=codec,
             strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
         )
         return (new_p, new_o), loss
@@ -1756,8 +1791,8 @@ def _engine_superstep_staged(
 
 def _engine_superstep(
     loss_fn, params, outer, key, px, py, counts, spe_arr, lrs,
-    *, K, m, shards, E, spe, B, has_labels, codec, strategy, interpret,
-    accum_dtype, axis_name=None,
+    *, K, m, shards, E, spe, B, feature_shape, has_labels, codec, strategy,
+    interpret, accum_dtype, axis_name=None,
 ):
     """R = len(lrs) full rounds fused into one ``lax.scan``: per round, the
     carry key splits into (cohort draw, data/codec key, next carry) exactly
@@ -1790,7 +1825,8 @@ def _engine_superstep(
                                                      m_local)
         new_p, new_o, loss = _engine_round(
             loss_fn, p, o, px, py, counts, spe_arr, ids, valid, k_data, lr,
-            E=E, spe=spe, B=B, has_labels=has_labels, codec=codec,
+            E=E, spe=spe, B=B, feature_shape=feature_shape,
+            has_labels=has_labels, codec=codec,
             strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
             axis_name=axis_name,
         )
@@ -1814,7 +1850,7 @@ def _engine_superstep(
 
 def _engine_gossip_round(
     loss_fn, stacked, px, py, counts, spe_arr, mix_idx, mix_w, key, lr,
-    *, E, spe, B, has_labels, interpret, accum_dtype,
+    *, E, spe, B, feature_shape, has_labels, interpret, accum_dtype,
 ):
     """One fused gossip round over the (n_nodes, ...) replica stack.
     Returns (mixed replica stack, cohort train loss, consensus distance).
@@ -1829,7 +1865,7 @@ def _engine_gossip_round(
     ids = jnp.arange(n_nodes, dtype=jnp.int32)
     batch, mask, w = _assemble_batches(
         px, py, counts, spe_arr, ids, key, E=E, spe=spe, B=B,
-        has_labels=has_labels,
+        feature_shape=feature_shape, has_labels=has_labels,
     )
     upd = jax.vmap(
         lambda p, b, msk: client_update(loss_fn, p, b, msk, lr)
@@ -1849,7 +1885,7 @@ def _engine_gossip_round(
 
 def _engine_gossip_superstep(
     loss_fn, stacked, key, px, py, counts, spe_arr, mix_idx, mix_w, lrs,
-    *, E, spe, B, has_labels, interpret, accum_dtype,
+    *, E, spe, B, feature_shape, has_labels, interpret, accum_dtype,
 ):
     """R = len(lrs) gossip rounds fused into one ``lax.scan``. The carry
     key splits into (data key, next carry) exactly as the eager
@@ -1862,7 +1898,8 @@ def _engine_gossip_superstep(
         k_data, k_next = jax.random.split(k)
         new_p, loss, cons = _engine_gossip_round(
             loss_fn, p, px, py, counts, spe_arr, mix_idx, mix_w, k_data, lr,
-            E=E, spe=spe, B=B, has_labels=has_labels, interpret=interpret,
+            E=E, spe=spe, B=B, feature_shape=feature_shape,
+            has_labels=has_labels, interpret=interpret,
             accum_dtype=accum_dtype,
         )
         return (new_p, k_next), (loss, cons)
@@ -1886,7 +1923,7 @@ def _engine_gossip_superstep(
 
 def _engine_client_phase(
     loss_fn, params, px, py, counts, spe_arr, ids, valid, key, lr,
-    *, E, spe, B, has_labels,
+    *, E, spe, B, feature_shape, has_labels,
 ):
     """Dispatch half of a round: run ClientUpdate for a cohort against the
     CURRENT params and return the raw ingredients the server buffers —
@@ -1896,7 +1933,7 @@ def _engine_client_phase(
 
     batch, mask, w = _assemble_batches(
         px, py, counts, spe_arr, ids, key, E=E, spe=spe, B=B,
-        has_labels=has_labels,
+        feature_shape=feature_shape, has_labels=has_labels,
     )
     w = w * valid
     upd = jax.vmap(

@@ -278,6 +278,11 @@ class StreamedClientPool(ClientPool):
         return self._y_dtype is not None
 
     @property
+    def feature_shape(self) -> Tuple[int, ...]:
+        """One example's x shape: the tail of ``gather``'s (m, n_pad, ...)."""
+        return tuple(int(d) for d in self._x_tail)
+
+    @property
     def num_shards(self) -> int:
         return len(self._shard_rows)
 

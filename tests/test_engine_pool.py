@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import FedAvgConfig, RoundEngine, quantize_codec
 from repro.core.strategies import FedAvgM
@@ -300,3 +301,90 @@ def test_from_spec_streamed_pool(setup):
     eng.run(4)
     dev.run(4)
     _assert_same_run(dev, eng)
+
+
+# ---------------------------------------------------------------------------
+# the (K, n_pad, F) row layout == assembly from the unflattened pool
+# ---------------------------------------------------------------------------
+
+FEATURES = {
+    "image": (28, 28, 1),
+    "flat": (784,),
+    "tokens": (5,),
+}
+
+
+def _featured_clients(features, k=10, seed=0):
+    rng = np.random.default_rng(seed)
+    sizes = rng.integers(5, 14, k)
+    if features == "tokens":
+        return [(rng.integers(0, 7, (n, 5)).astype(np.int32),
+                 rng.integers(0, 7, (n, 5)).astype(np.int32)) for n in sizes]
+    return [(rng.normal(size=(n,) + FEATURES[features]).astype(np.float32),
+             rng.integers(0, 10, n).astype(np.int32)) for n in sizes]
+
+
+def _unflattened_assembly(monkeypatch):
+    """The assembly the row layout replaced: the pool kept as (K, n_pad,
+    *feature_shape), the cohort gathered whole (``take(px, ids)``), then
+    ``take(rows, perm)``. ``perm`` is the engine's own draw, read off a
+    pool of row indices."""
+    from repro.core import engine
+
+    draw = engine._assemble_cohort_batches
+
+    def assemble(px, py, ids, w, spe_k, key, *, feature_shape, has_labels,
+                 **kw):
+        xs = jnp.take(px, ids, axis=0)
+        ys = jnp.take(py, ids, axis=0) if has_labels else None
+        m, n_pad = xs.shape[:2]
+        rows = jnp.broadcast_to(
+            jnp.arange(n_pad, dtype=jnp.int32)[None, :, None], (m, n_pad, 1)
+        )
+        (perm,), mask, w = draw(rows, None, jnp.arange(m), w, spe_k, key,
+                                feature_shape=(), has_labels=False, **kw)
+        take = jax.vmap(lambda r, p: jnp.take(r, p, axis=0))
+        batch = (take(xs, perm),) + ((take(ys, perm),) if has_labels else ())
+        return batch, mask, w
+
+    monkeypatch.setattr(engine, "_as_rows", lambda x: x)
+    monkeypatch.setattr(engine, "_assemble_cohort_batches", assemble)
+
+
+@pytest.mark.parametrize("C", [0.1, 1.0], ids=["m1", "m10"])
+@pytest.mark.parametrize("lane", ["device", "streamed", "sharded"])
+@pytest.mark.parametrize("features", sorted(FEATURES))
+def test_row_pool_matches_unflattened_assembly_bitwise(features, lane, C,
+                                                        monkeypatch):
+    from repro.launch.mesh import make_client_mesh
+    from repro.models import char_lstm, mnist_2nn
+
+    model = (char_lstm(7, embed_dim=4, hidden=8) if features == "tokens"
+             else mnist_2nn())
+    params = model.init(jax.random.PRNGKey(1))
+    clients = _featured_clients(features)
+    cfg = FedAvgConfig(C=C, E=2, B=4, lr=0.1, seed=5)
+
+    def build():
+        kw = ({"mesh": make_client_mesh()} if lane == "sharded"
+              else {"pool": lane})
+        return RoundEngine(model.loss, params, clients, cfg, **kw)
+
+    def one_round(e):
+        ids, key = build()._sample_ids_host()  # round 0's draw, replayed
+        batch = e.materialize_round_batch(ids, key)
+        loss = e.round()["loss"]
+        return jax.tree.map(np.asarray, (batch, e.params, e.outer_state, loss))
+
+    eng = build()
+    if lane != "streamed":
+        assert eng._x.ndim == 3  # (K, n_pad, F)
+    got = one_round(eng)
+    _unflattened_assembly(monkeypatch)
+    ref = build()
+    want = one_round(ref)
+    (bx, _), _, _ = want[0]
+    assert bx.shape[3:] == FEATURES[features]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)

@@ -128,3 +128,41 @@ def test_gossip_mix_compiles_for_v5e(one_chip, kind, nodes, n):
         ((nodes, slots), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_batch_assembly_gathers_rows_for_v5e(one_chip):
+    """The q4 benchmark cell's assembly (K=100 clients of 600 28x28x1
+    examples, m=100, FedSGD: E=1, B=600) feeding the 2NN's loss, whose first
+    op flattens each example. The pool stored as (K, n_pad, 784) rows makes
+    the cohort gather a row copy; stored as (K, n_pad, 28, 28, 1), the
+    example index lands in the lanes and this program reads 66.9 GB. Alone,
+    without a consumer, the assembly must also lay out its 28x28x1 output,
+    so the guard compiles it with the model it feeds."""
+    from repro.core.engine import _assemble_batches
+    from repro.models import mnist_2nn
+
+    K, n_pad, m, B = 100, 600, 100, 600
+    model = mnist_2nn()
+
+    def first_step_loss(params, px, py, counts, spe_arr, ids, key):
+        (bx, by), mask, w = _assemble_batches(
+            px, py, counts, spe_arr, ids, key, E=1, spe=1, B=B,
+            feature_shape=(28, 28, 1), has_labels=True,
+        )
+        losses = jax.vmap(lambda x, y: model.loss(params, (x[0], y[0]))[0])(
+            bx, by
+        )
+        return jnp.sum(losses * mask[:, 0] * w)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+    )
+    shapes = [((K, n_pad, 784), jnp.float32), ((K, n_pad), jnp.int32),
+              ((K,), jnp.float32), ((K,), jnp.int32), ((m,), jnp.int32),
+              ((2,), jnp.uint32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    cost = jax.jit(first_step_loss).lower(params, *args).compile()
+    cost = cost.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 5e9, cost["bytes accessed"]

@@ -5,14 +5,26 @@ A cell (an entry of ``workloads``) joins a configuration and a traffic mix:
 
 - ``configs/<config>.json``: the model by its registry name, its layer
   sizes and its client population; ``configs/<config>.py`` beside it is the
-  plain reference of the model (``init``, ``apply``).
+  plain reference of the model (``init``, ``apply``). The population is of
+  images (``input_shape``, ``n_classes``) unless the file says
+  ``"population": "tokens"``: token sequences for a next-token model, with
+  ``vocab_size``, ``seq_len`` and ``n_topics`` (sequences of a topic share
+  a token source; the partition splits on the topic). Both give
+  ``clients`` and ``examples_per_client`` (images or sequences). With
+  ``"reference_clients_per_block": c`` the reference trains the cohort c
+  clients at a time, for a cohort whose copies of the weights do not fit
+  on the chip at once.
 - ``traffic/<traffic>.json``: overrides on the program's own
   ``ExperimentSpec`` JSON form (partition, C, E, B, lr, strategy, codec,
   execution lane) and how many rounds one timed call runs.
 - ``limits/<cell>.json``: the limit of each number that decides
   ``correct``, with the readings it was set from.
 - ``flops/<model>.py``: the training FLOPs a round must do, from shapes.
-- ``layer_metrics/<metric>.py``: one reader per per-layer metric.
+- ``layer_metrics/<metric>.py``: one reader per per-layer metric. Its
+  ``compute(ctx)`` gets the trace reduced two ways: ``ctx["trace"]``
+  (``trace_reduce.Reduced``: device ops and idle time) and ``ctx["spans"]``
+  (``span_reduce.Spans``: device time by the program's named scopes, and
+  device idle time inside its host-loop spans).
 
 Adding a configuration, a traffic mix, a cell or a metric adds such files
 and ``BENCHMARK.json`` entries; nothing here changes.
@@ -38,7 +50,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from benchmarks.chip import compare, population, trace_reduce
+from benchmarks.chip import compare, population, span_reduce, trace_reduce
 from benchmarks.chip.peaks import peak
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -141,6 +153,11 @@ def validate(bench: dict, bench_dir: Path = BENCH_DIR) -> None:
         load_reader(bench_dir, metric["name"])
     for name in cells:
         cell = load_cell(name, bench, bench_dir)
+        population.check_config(cell.config)
+        block = cell.config.get("reference_clients_per_block")
+        if block is not None and (not isinstance(block, int) or block < 1):
+            raise ValueError(f"{name}: reference_clients_per_block must be a "
+                             f"positive whole number, not {block!r}")
         named = [k for k in cell.limits if k in compare.NUMBERS]
         if not named or any("limit" not in cell.limits[k] for k in named):
             raise KeyError(f"limits/{name}.json names no number with a "
@@ -294,7 +311,9 @@ def reference_of(cell: Cell, su: SetUp, **kw) -> dict:
 
     return reference.run_reference(
         cell.model.apply, su.clients, su.p0, cell.spec_overrides, su.seed,
-        su.n_setup, su.snapshot_rounds, **kw,
+        su.n_setup, su.snapshot_rounds,
+        clients_per_block=cell.config.get("reference_clients_per_block"),
+        **kw,
     )
 
 
@@ -402,8 +421,9 @@ def end_to_end_metrics(cell, window_s, rounds, walls, setup_s) -> dict:
 
 
 def per_layer_metrics(cell, trace_dir, window_s, rounds, devices):
-    red = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir),
-                                    ANNOTATION)
+    xplane = trace_reduce.find_xplane(trace_dir)
+    red = trace_reduce.reduce_trace(xplane, ANNOTATION)
+    spans = span_reduce.reduce_trace(xplane, ANNOTATION)
     fed = cell.spec_overrides["fedavg"]
     k = int(cell.config["clients"])
     m = max(int(round(float(fed["C"]) * k)), 1)
@@ -411,6 +431,7 @@ def per_layer_metrics(cell, trace_dir, window_s, rounds, devices):
     flops_file = cell.bench_dir / "flops" / f"{cell.config['model']['kind']}.py"
     ctx = {
         "trace": red,
+        "spans": spans,
         "window_s": window_s,
         "rounds": rounds,
         "chips": len(devices),
@@ -432,6 +453,7 @@ def per_layer_metrics(cell, trace_dir, window_s, rounds, devices):
     log("trace: " + json.dumps({
         "window_s": red.window_s, "busy_s": red.busy_s,
         "top_ops": trace_reduce.breakdown(red, 25)["device_ops"],
+        "spans_ms_per_round": span_reduce.per_round_ms(spans, rounds),
         "op_text": {k: v[:300] for k, v in sorted(
             red.op_text.items(), key=lambda kv: -red.op_s[kv[0]])[:25]},
     }))

@@ -18,6 +18,12 @@ test without reading anything the system made.
 reference; bfloat16 everywhere is the lower-precision control. ``fault=
 "half_batch"`` plants a fault the comparison must catch: every minibatch
 trains on its first half, the mean taken over it.
+
+Labels are class ids of any shape: one per example, or one per position of
+a token sequence. ``clients_per_block`` runs the cohort that many clients
+at a time, for a cohort whose copies of the weights do not fit at once:
+each client's draws stay keyed by its slot in the whole cohort, and a
+float32 running sum holds the count-weighted changes.
 """
 from __future__ import annotations
 
@@ -54,16 +60,16 @@ def cohort_schedule(traffic_spec: dict, n_clients: int, seed: int,
     return out
 
 
-def _client_orders(key, m, n, epochs):
-    """``(m, epochs * n)`` row order: one uniform permutation of each
-    client's n rows per epoch, keyed by the client's slot in the cohort and
-    the epoch."""
+def _client_orders(key, slots, n, epochs):
+    """``(len(slots), epochs * n)`` row order: one uniform permutation of
+    each client's n rows per epoch, keyed by the client's slot in the cohort
+    and the epoch."""
     def one(slot):
         ck = jax.random.fold_in(key, slot)
         return jax.vmap(lambda e: jnp.argsort(
             jax.random.uniform(jax.random.fold_in(ck, e), (n,))
         ))(jnp.arange(epochs, dtype=jnp.int32)).reshape(-1)
-    return jax.vmap(one)(jnp.arange(m, dtype=jnp.int32))
+    return jax.vmap(one)(slots)
 
 
 def _quantize_roundtrip(flat, key, bits, chunk):
@@ -81,15 +87,23 @@ def _quantize_roundtrip(flat, key, bits, chunk):
     return (q * (scale / levels)[:, None] + lo[:, None]).reshape(-1)[:n]
 
 
-def _round(apply, params, xs, ys, weights, key, lr, *, epochs, batch,
-           precision, codec, fault):
-    """One round over the cohort's rows ``xs`` ``(m, n, ...)``. Returns the
-    new global weights and the round's train loss (the count-weighted mean
-    over clients of each client's mean loss over its steps)."""
+def cross_entropy(logits, y):
+    """Mean softmax cross-entropy of float32 ``logits`` ``(..., classes)``
+    against class ids ``y`` of the leading shape."""
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
+    return jnp.mean(logz - gold)
+
+
+def _clients(apply, params, xs, ys, slots, key, lr, *, epochs, batch,
+             precision, codec, fault):
+    """Local training of the clients in cohort ``slots`` from the global
+    weights, on their rows ``xs`` ``(c, n, ...)``. Returns each client's
+    change (after the upload codec) and its mean loss over its steps."""
     m, n = xs.shape[:2]
     b = n if batch is None else int(batch)
     steps = epochs * (n // b)
-    order = _client_orders(key, m, n, epochs).reshape(m, steps, b)
+    order = _client_orders(key, slots, n, epochs).reshape(m, steps, b)
     if fault == "half_batch":
         half = order[:, :, : b // 2]
         order = jnp.concatenate([half, half], axis=2)[:, :, :b]
@@ -98,10 +112,7 @@ def _round(apply, params, xs, ys, weights, key, lr, *, epochs, batch,
     dtype = jax.tree.leaves(params)[0].dtype
 
     def loss_fn(p, x, y):
-        logits = apply(p, x, precision).astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-        return jnp.mean(logz - gold)
+        return cross_entropy(apply(p, x, precision).astype(jnp.float32), y)
 
     def client(x_steps, y_steps):
         def sgd(w, xy):
@@ -111,16 +122,12 @@ def _round(apply, params, xs, ys, weights, key, lr, *, epochs, batch,
         return jax.lax.scan(sgd, params, (x_steps, y_steps))
 
     client_params, losses = jax.vmap(client)(bx, by)
-    wn = weights / jnp.sum(weights)
-    loss = jnp.sum(wn * jnp.mean(losses, axis=1))
     deltas = jax.tree.map(lambda c, p: c - p, client_params, params)
     if codec is not None:
         leaves, treedef = jax.tree.flatten(deltas)
         flat = jnp.concatenate([l.reshape(m, -1) for l in leaves], axis=1)
         ckey = jax.random.fold_in(key, CODEC_KEY_SALT)
-        keys = jax.vmap(lambda s: jax.random.fold_in(ckey, s))(
-            jnp.arange(m, dtype=jnp.int32)
-        )
+        keys = jax.vmap(lambda s: jax.random.fold_in(ckey, s))(slots)
         flat = jax.vmap(partial(
             _quantize_roundtrip, bits=int(codec["bits"]),
             chunk=int(codec["chunk"]),
@@ -131,19 +138,53 @@ def _round(apply, params, xs, ys, weights, key, lr, *, epochs, batch,
             out.append(flat[:, off:off + size].reshape(l.shape))
             off += size
         deltas = jax.tree.unflatten(treedef, out)
-    wd = wn.astype(dtype)
+    return deltas, jnp.mean(losses, axis=1)
+
+
+def _weighted_sum(wn, deltas, loss, precision):
+    """The count-weighted sum of the clients' changes and losses; ``wn``
+    are the clients' shares of the whole cohort's examples."""
+    wd = wn.astype(jax.tree.leaves(deltas)[0].dtype)
     avg = jax.tree.map(
         lambda d: jnp.tensordot(wd, d, axes=1, precision=precision), deltas
     )
+    return avg, jnp.sum(wn * loss)
+
+
+def _round(apply, params, xs, ys, weights, key, lr, *, epochs, batch,
+           precision, codec, fault):
+    """One round over the whole cohort's rows ``xs`` ``(m, n, ...)`` at
+    once. Returns the new global weights and the round's train loss (the
+    count-weighted mean over clients of each client's mean loss over its
+    steps)."""
+    slots = jnp.arange(xs.shape[0], dtype=jnp.int32)
+    deltas, loss = _clients(apply, params, xs, ys, slots, key, lr,
+                            epochs=epochs, batch=batch, precision=precision,
+                            codec=codec, fault=fault)
+    avg, loss = _weighted_sum(weights / jnp.sum(weights), deltas, loss,
+                              precision)
     return jax.tree.map(lambda p, a: p + a, params, avg), loss
+
+
+def _block(apply, params, xs, ys, slots, wn, key, lr, acc, loss, *,
+           precision, **kw):
+    """The clients in ``slots`` added to the running float32 sums ``acc``
+    (weighted changes) and ``loss``."""
+    deltas, losses = _clients(apply, params, xs, ys, slots, key, lr,
+                              precision=precision, **kw)
+    avg, part = _weighted_sum(wn, deltas, losses, precision)
+    acc = jax.tree.map(lambda a, d: a + d.astype(jnp.float32), acc, avg)
+    return acc, loss + part
 
 
 def run_reference(apply, clients, init_params, traffic_spec, seed, n_rounds,
                   snapshot_at, *, dtype=jnp.float32,
-                  precision=jax.lax.Precision.HIGHEST, fault=None):
+                  precision=jax.lax.Precision.HIGHEST, fault=None,
+                  clients_per_block=None):
     """Train ``n_rounds`` reference rounds from ``init_params``. Returns
     ``{"losses": [...], "params": {r: host tree after round r}}`` for each
-    ``r`` in ``snapshot_at``."""
+    ``r`` in ``snapshot_at``. ``clients_per_block``: run the cohort that
+    many clients at a time (``None``: all at once)."""
     sizes = {len(x) for x, _ in clients}
     if len(sizes) != 1:
         raise ValueError("the reference round needs clients of equal size")
@@ -158,10 +199,13 @@ def run_reference(apply, clients, init_params, traffic_spec, seed, n_rounds,
     xs_all = jnp.asarray(np.stack([x for x, _ in clients]))
     ys_all = jnp.asarray(np.stack([y for _, y in clients]))
     weights_all = jnp.asarray([len(x) for x, _ in clients], jnp.float32)
-    step = jax.jit(partial(
-        _round, apply, epochs=epochs, batch=batch, precision=precision,
-        codec=codec, fault=fault,
-    ))
+    kw = dict(epochs=epochs, batch=batch, precision=precision, codec=codec,
+              fault=fault)
+    if clients_per_block is None:
+        step = jax.jit(partial(_round, apply, **kw))
+    else:
+        block = jax.jit(partial(_block, apply, **kw), donate_argnums=(7,))
+        step = partial(_blocked_round, block, int(clients_per_block))
     lr0 = float(fed["lr"])
     decay = float(fed.get("lr_decay", 1.0))
     params = jax.tree.map(lambda a: jnp.asarray(a, dtype), init_params)
@@ -178,3 +222,17 @@ def run_reference(apply, clients, init_params, traffic_spec, seed, n_rounds,
                 lambda a: np.array(a, np.float32), params
             )
     return {"losses": losses, "params": snaps}
+
+
+def _blocked_round(block, c, params, xs, ys, weights, key, lr):
+    """:func:`_round` with the cohort run ``c`` clients at a time by the
+    jitted :func:`_block`."""
+    m = xs.shape[0]
+    wn = weights / jnp.sum(weights)
+    acc = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    loss = jnp.float32(0)
+    for s in range(0, m, c):
+        slots = jnp.arange(s, min(s + c, m), dtype=jnp.int32)
+        acc, loss = block(params, xs[s:s + c], ys[s:s + c], slots, wn[s:s + c],
+                          key, lr, acc, loss)
+    return jax.tree.map(lambda p, a: p + a.astype(p.dtype), params, acc), loss

@@ -12,6 +12,10 @@ steps), this gives (:class:`Spans`):
 - ``scope_s``: device self time, averaged over devices, by the innermost
   ``fedavg.*`` segment of each op's ``op_name``, ``"unscoped"`` for ops
   under none;
+- ``segment_s``: device self time, averaged over devices, under each
+  segment of the ops' ``op_name`` (:func:`segments_of`), whichever layer
+  opened it: a reader of a model's own scope, such as ``model.moe``, reads
+  its key here;
 - ``span_idle_s``: idle device time, averaged over devices, inside each of
   the loop spans: the exact overlap of the idle intervals with the spans,
   once each device's intervals are moved onto the host's clock
@@ -42,7 +46,7 @@ import re
 import sys
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, Optional, Sequence
+from typing import Dict, FrozenSet, Optional, Sequence
 
 if __package__ in (None, ""):
     sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
@@ -56,6 +60,7 @@ ENQUEUE = "DoEnqueueProgram"
 RUN_ID = "run_id"
 OP_NAME_STAT = "tf_op"
 SCOPE = re.compile(r"fedavg\.[A-Za-z_]+")
+TRANSFORM = re.compile(r"[A-Za-z_][A-Za-z0-9_]*\((.*)\)")
 UNSCOPED = "unscoped"
 LOOP_SPANS = ("fedavg.prepare", "fedavg.dispatch", "fedavg.sync")
 PROGRAM_STEPS = ("fedavg.round", "fedavg.superstep")
@@ -78,6 +83,7 @@ class Spans:
     idle_s: float
     scope_s: Dict[str, float]
     span_idle_s: Dict[str, float]
+    segment_s: Dict[str, float]
 
 
 def scope_of(op_path: str) -> str:
@@ -86,6 +92,21 @@ def scope_of(op_path: str) -> str:
     operation's ``op_name``, or ``"unscoped"``."""
     found = SCOPE.findall(op_path)
     return found[-1] if found else UNSCOPED
+
+
+def segments_of(op_path: str) -> FrozenSet[str]:
+    """``jit(f)/while/body/transpose(jvp(model.moe))/dot_general`` ->
+    ``{f, while, body, model.moe}``: every segment of an operation's
+    ``op_name`` but the last (the operation itself), without the transforms
+    JAX wraps round a scope, so that a scope's forward and backward ops
+    count under one name."""
+    out = set()
+    for part in op_path.split("/")[:-1]:
+        while (m := TRANSFORM.fullmatch(part)):
+            part = m.group(1)
+        if part:
+            out.add(part)
+    return frozenset(out)
 
 
 def per_round_ms(spans: Spans, rounds: int) -> Dict[str, Optional[float]]:
@@ -249,6 +270,7 @@ def reduce_events(device_ops, host_spans,
     }
     loop_spans = {k: v for k, v in loop_spans.items() if v}
     scope_ns, span_idle_ns = defaultdict(float), defaultdict(float)
+    segment_ns = defaultdict(float)
     idle_ns = 0.0
     n_dev = max(len(device_ops), 1)
     for device, events in device_ops.items():
@@ -256,6 +278,8 @@ def reduce_events(device_ops, host_spans,
                   if min(e, w1) > max(s, w0)]
         for (_, _, _, path), dt in zip(inside, _self_times(inside)):
             scope_ns[scope_of(path)] += dt / n_dev
+            for seg in segments_of(path):
+                segment_ns[seg] += dt / n_dev
         merged = _merge([(s, e) for _, s, e, _ in inside])
         idle_ns += ((w1 - w0) - sum(e - s for s, e in merged)) / n_dev
         shift = (clock_shift or {}).get(device, 0)
@@ -273,6 +297,7 @@ def reduce_events(device_ops, host_spans,
         idle_s=idle_ns * 1e-9,
         scope_s={k: v * 1e-9 for k, v in scope_ns.items()},
         span_idle_s={k: v * 1e-9 for k, v in span_idle_ns.items()},
+        segment_s={k: v * 1e-9 for k, v in segment_ns.items()},
     )
 
 

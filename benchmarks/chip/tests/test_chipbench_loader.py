@@ -128,6 +128,39 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
     assert [m["name"] for m in cell.per_layer] == ["device_idle_share"]
 
 
+@pytest.mark.parametrize("change, error", [
+    ({"n_topics": None}, KeyError),
+    ({"seq_len": None}, KeyError),
+    ({"reference_clients_per_block": 0}, ValueError),
+    ({"reference_clients_per_block": 1.5}, ValueError),
+], ids=["no_n_topics", "no_seq_len", "block_0", "block_1.5"])
+def test_a_token_config_is_checked_before_a_run(tmp_path, change, error):
+    """A configuration of token sequences must name its population's keys
+    and a whole number of clients for each reference block."""
+    bench_dir = tmp_path / "chip"
+    shutil.copytree(harness.BENCH_DIR, bench_dir,
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    cfg = {"name": "tokens", "model": {"kind": "char_lstm",
+                                       "kwargs": {"vocab_size": 40}},
+           "population": "tokens", "vocab_size": 40, "seq_len": 16,
+           "clients": 4, "examples_per_client": 8, "n_topics": 4,
+           "reference_clients_per_block": 2}
+    bench = json.loads(json.dumps(BENCH))
+    first = bench["workloads"][0]
+    bench["workloads"].append(dict(first, name="tokens_cell",
+                                   config="tokens"))
+    shutil.copy(bench_dir / "limits" / f"{first['name']}.json",
+                bench_dir / "limits" / "tokens_cell.json")
+    shutil.copy(bench_dir / "configs" / f"{first['config']}.py",
+                bench_dir / "configs" / "tokens.py")
+    (bench_dir / "configs" / "tokens.json").write_text(json.dumps(cfg))
+    harness.validate(bench, bench_dir)
+    broken = {k: v for k, v in {**cfg, **change}.items() if v is not None}
+    (bench_dir / "configs" / "tokens.json").write_text(json.dumps(broken))
+    with pytest.raises(error):
+        harness.validate(bench, bench_dir)
+
+
 def _run(args, cwd, env_extra=None):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     env.update({"JAX_PLATFORMS": "cpu"}, **(env_extra or {}))

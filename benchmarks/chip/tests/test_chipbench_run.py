@@ -2,11 +2,14 @@
 chip: the reference agrees with the program; the bfloat16 control and
 faults planted in the program come out not correct.
 
-The tiny cell is the 2NN at its published widths over 8 clients of 20
-examples, added by files alone (a configuration, two traffic mixes and
-their limits in a copy of the benchmark's directory). Its limits are this
-size's own, set between what the program reads on the CPU (under 3e-6)
-and what the control reads (above 1e-3).
+The tiny cells are added by files alone (configurations with their
+references, traffic mixes and limits in a copy of the benchmark's
+directory): the 2NN at its published widths over 8 clients of 20
+examples, and the program's character LSTM over 4 silos of token
+sequences, one topic a silo, with its reference (written here) run two
+clients at a time. Their limits are this size's own, set between what the
+program reads on the CPU (under 3e-6) and what the control reads (above
+1e-3).
 """
 from __future__ import annotations
 
@@ -44,7 +47,79 @@ TRAFFIC = {
                                "rounds_per_step": 2}},
         "rounds_per_call": 2,
     },
+    "tiny_tokens": {
+        "spec": {"partition": {"kind": "pathological_noniid",
+                               "shards_per_client": 1},
+                 "fedavg": {"C": 1.0, "E": 1, "B": 4, "lr": 0.5},
+                 "strategy": {"kind": "fedavg"}, "codec": None,
+                 "execution": {}},
+        "rounds_per_call": 1,
+    },
 }
+CONFIG_OF = {"tiny_dense": "tiny_2nn", "tiny_q4": "tiny_2nn",
+             "tiny_tokens": "tiny_char"}
+TINY_CHAR = {
+    "name": "tiny_char",
+    "model": {"kind": "char_lstm",
+              "kwargs": {"vocab_size": 24, "embed_dim": 8, "hidden": 32}},
+    "population": "tokens", "vocab_size": 24, "seq_len": 12, "clients": 4,
+    "examples_per_client": 8, "n_topics": 4,
+    "reference_clients_per_block": 2,
+}
+# The plain reference of the program's two-layer character LSTM
+# (``repro.models.char_lstm``), in the weight layout the program takes.
+CHAR_LSTM_REFERENCE = '''
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def _glorot(key, shape, dtype):
+    lim = math.sqrt(6.0 / (shape[0] + shape[1]))
+    return jax.random.uniform(key, shape, dtype, -lim, lim)
+
+
+def _lstm_init(key, d_in, d_hidden, dtype):
+    k1, k2 = jax.random.split(key)
+    return {"wx": _glorot(k1, (d_in, 4 * d_hidden), dtype),
+            "wh": _glorot(k2, (d_hidden, 4 * d_hidden), dtype),
+            "b": jnp.zeros((4 * d_hidden,), dtype)}
+
+
+def init(key, config, dtype=jnp.float32):
+    kw = config["model"]["kwargs"]
+    v, e, h = config["vocab_size"], kw["embed_dim"], kw["hidden"]
+    k = jax.random.split(key, 4)
+    return {"embed": 0.1 * jax.random.normal(k[0], (v, e), dtype),
+            "lstm1": _lstm_init(k[1], e, h, dtype),
+            "lstm2": _lstm_init(k[2], h, h, dtype),
+            "out": {"w": _glorot(k[3], (h, v), dtype),
+                    "b": jnp.zeros((v,), dtype)}}
+
+
+def _lstm(p, x, precision):
+    def cell(carry, x_t):
+        h, c = carry
+        gates = (jnp.dot(x_t, p["wx"], precision=precision)
+                 + jnp.dot(h, p["wh"], precision=precision) + p["b"])
+        i, f, g, o = jnp.split(gates, 4, axis=-1)
+        c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
+        h = jax.nn.sigmoid(o) * jnp.tanh(c)
+        return (h, c), h
+
+    zeros = jnp.zeros((x.shape[0], p["wh"].shape[0]), x.dtype)
+    _, hs = jax.lax.scan(cell, (zeros, zeros), jnp.swapaxes(x, 0, 1))
+    return jnp.swapaxes(hs, 0, 1)
+
+
+def apply(params, x, precision):
+    """Next-token logits (B, S, V) of token ids x (B, S)."""
+    h = params["embed"][x]
+    h = _lstm(params["lstm2"], _lstm(params["lstm1"], h, precision),
+              precision)
+    return jnp.dot(h, params["out"]["w"], precision=precision) + params["out"]["b"]
+'''
 
 
 @pytest.fixture(scope="module")
@@ -57,12 +132,14 @@ def tiny(tmp_path_factory):
     (bench_dir / "configs" / "tiny_2nn.json").write_text(json.dumps(cfg))
     shutil.copy(bench_dir / "configs" / "mnist_2nn.py",
                 bench_dir / "configs" / "tiny_2nn.py")
+    (bench_dir / "configs" / "tiny_char.json").write_text(json.dumps(TINY_CHAR))
+    (bench_dir / "configs" / "tiny_char.py").write_text(CHAR_LSTM_REFERENCE)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     limits = {k: {"limit": TINY_LIMIT} for k in compare.NUMBERS}
     for name, traffic in TRAFFIC.items():
         (bench_dir / "traffic" / f"{name}.json").write_text(json.dumps(traffic))
         (bench_dir / "limits" / f"{name}.json").write_text(json.dumps(limits))
-        bench["workloads"].append({"name": name, "config": "tiny_2nn",
+        bench["workloads"].append({"name": name, "config": CONFIG_OF[name],
                                    "traffic": name, "chips": 1, "why": "test"})
         for metric in bench["end_to_end"]:
             if metric["name"] == "round_s":

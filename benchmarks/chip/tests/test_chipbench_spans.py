@@ -1,11 +1,13 @@
 """The span reduction (``span_reduce.py``) on traces built by hand: device
-self time by ``fedavg.*`` scope, device idle time inside the round loop's
-host spans (with each device moved onto the host's clock), and the
-per-round readings."""
+self time by ``fedavg.*`` scope and by every named-scope segment, device
+idle time inside the round loop's host spans (with each device moved onto
+the host's clock), the per-round readings, and the per-layer readers that
+take them from ``ctx["spans"]``."""
 from __future__ import annotations
 
 import json
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -15,7 +17,7 @@ for _p in (ROOT, ROOT / "src"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from benchmarks.chip import span_reduce, trace_reduce  # noqa: E402
+from benchmarks.chip import harness, span_reduce, trace_reduce  # noqa: E402
 
 MS = 1_000_000  # ns
 
@@ -67,6 +69,38 @@ def test_scope_time_goes_to_the_innermost_scope_averaged_over_devices():
     assert red.scope_s[span_reduce.UNSCOPED] == pytest.approx(0.015 / 2)
     # self times: the scopes partition the busy time
     assert sum(red.scope_s.values()) == pytest.approx(_busy_s(red))
+
+
+def test_segment_time_agrees_with_the_scope_time():
+    red = _reduced()
+    seg, scope = red.segment_s, red.scope_s
+    # A fedavg scope with none nested in it holds what scope_s gives it;
+    # client_update holds the encode op nested in it as well.
+    for name in ("fedavg.assemble", "fedavg.encode"):
+        assert seg[name] == pytest.approx(scope[name])
+    assert seg["fedavg.client_update"] == pytest.approx(
+        scope["fedavg.client_update"] + scope["fedavg.encode"])
+    # Every op with an op_name is under jit(f): the busy time less the op
+    # without one.
+    assert seg["f"] == pytest.approx(_busy_s(red) - 0.005 / 2)
+    # The loop's body op, not the loop's own time (the while op itself).
+    assert seg["while"] == seg["body"] == pytest.approx(0.020 / 2)
+    assert span_reduce.UNSCOPED not in seg
+    assert _reduced(with_paths=False).segment_s == {}
+
+
+@pytest.mark.parametrize("path, segments", [
+    ("jit(f)/while/body/transpose(jvp(model.moe))/dot_general",
+     {"f", "while", "body", "model.moe"}),
+    ("jit(step)/fedavg.client_update/vmap()/while/body/jvp(model.mla)/tanh",
+     {"step", "fedavg.client_update", "while", "body", "model.mla"}),
+    ("jit(f)/fedavg.assemble/gather:", {"f", "fedavg.assemble"}),
+    ("jit(f)/model.moe/model.moe/add", {"f", "model.moe"}),
+    ("fusion", set()),
+    ("", set()),
+])
+def test_segments_of(path, segments):
+    assert span_reduce.segments_of(path) == segments
 
 
 def test_span_idle_is_the_exact_overlap_of_idle_and_spans():
@@ -368,3 +402,33 @@ def test_per_round_ms_is_silent_without_its_span(name):
     host = [h for h in host if not h[0].startswith("fedavg.")]
     red = span_reduce.reduce_events(dev, host, "bench.call")
     assert span_reduce.per_round_ms(red, 2)[name] is None
+
+
+def test_readers_take_the_span_readings_from_ctx(tmp_path):
+    """``harness.per_layer_metrics`` on the hand-built trace: each cell's
+    readers find ``ctx["spans"]``; a reading whose scope or span the trace
+    lacks is left out."""
+    from jax.profiler import ProfileData
+
+    run = tmp_path / "plugins" / "profile" / "run"
+    run.mkdir(parents=True)
+    (run / "t.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(XSPACE))
+    chip = [types.SimpleNamespace(device_kind="TPU v5 lite")]
+    bench = harness.load_benchmark()
+    got = {}
+    for name in ("2nn_fedsgd_rounds", "2nn_fedsgd_q4_m100"):
+        cell = harness.load_cell(name, bench)
+        out, _ = harness.per_layer_metrics(cell, tmp_path, 0.1, 2, chip)
+        got[name] = {k: v["value"] for k, v in out.items()}
+        assert {v["unit"] for v in out.values()} <= {"ms", "%"}
+    # 35 ms busy of 100 (assemble 20, client_update 10, unscoped 5); idle
+    # 10 ms inside prepare and 20 inside sync; two rounds.
+    assert got["2nn_fedsgd_rounds"] == pytest.approx({
+        "device_idle_ms_per_round": 32.5, "assemble_ms_per_round": 10.0,
+        "local_update_ms_per_round": 5.0, "prepare_idle_ms_per_round": 5.0,
+        "sync_idle_ms_per_round": 10.0})
+    q4 = got["2nn_fedsgd_q4_m100"]
+    assert "encode_ms_per_round" not in q4 and "aggregate_share" not in q4
+    assert q4["assemble_ms_per_round"] == pytest.approx(10.0)
+    assert q4["device_idle_share"] == pytest.approx(65.0)

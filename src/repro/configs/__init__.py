@@ -7,7 +7,14 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import ModelConfig, MoEConfig, MLAConfig, SSMConfig, reduced
+from repro.configs.base import (
+    MLAConfig,
+    ModelConfig,
+    MoEConfig,
+    SSMConfig,
+    YaRNConfig,
+    reduced,
+)
 
 ARCHS = (
     "jamba_v0_1_52b",

@@ -36,6 +36,24 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` with ``type: yarn``): rotary frequencies blended
+    between the original and the ``factor``-interpolated ones over the
+    correction range that ``beta_fast``/``beta_slow`` rotations span at
+    ``original_max_position_embeddings``; the softmax scale is multiplied
+    by ``mscale(factor, mscale_all_dim)**2`` and cos/sin by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class MoEConfig:
     n_experts: int = 0            # routed experts
     n_shared_experts: int = 0
@@ -45,8 +63,20 @@ class MoEConfig:
     first_dense: int = 0          # leading dense layers (deepseek v3: 3)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeek-V2's balance loss (MoEGate with seq_aux, AddAuxiliaryLoss):
+    # f_e * P_e per sequence, averaged over sequences, and added to the
+    # gradient but not to the reported loss.
+    seq_aux: bool = False
     router_scoring: str = "softmax"   # softmax (v2) | sigmoid (v3)
     group_size: int = 4096        # token group for sort-based dispatch
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum to 1
+    dense_d_ff: int = 0           # leading dense layers' width (0: derived)
+    # One chip's share of an expert-parallel layer: it holds experts
+    # held_first .. held_first + n_held - 1 of the n_experts the router
+    # scores, and computes their part of the result dropless. 0 holds
+    # every expert, dispatched through the capacity buffer.
+    n_held: int = 0
+    held_first: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +95,7 @@ class ModelConfig:
     attn_bias: bool = False        # qwen2: bias on QKV only
     sliding_window: int = 0        # 0 = full attention
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YaRNConfig] = None
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
     attn_q_chunk: int = 1024       # blocked-attention tile sizes
     attn_k_chunk: int = 1024
@@ -161,6 +192,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
             n_shared_experts=min(cfg.moe.n_shared_experts, 1),
             first_dense=min(cfg.moe.first_dense, 1),
             group_size=64,
+            dense_d_ff=min(cfg.moe.dense_d_ff, 512),
         )
     if cfg.mla is not None:
         changes["mla"] = MLAConfig(

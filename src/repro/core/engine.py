@@ -87,6 +87,7 @@ import numpy as np
 from repro.core.fedavg import (
     FedAvgConfig,
     client_update,
+    counter_sums,
     masked_weighted_loss,
     sample_clients,
     sample_clients_device,
@@ -157,6 +158,7 @@ def build_simulation_round_step(
     accum_dtype=jnp.float32,
     axis_name: Optional[str] = None,
     strategy: Optional[ServerStrategy] = None,
+    clients_per_chunk: Optional[int] = None,
 ) -> RoundStep:
     """RoundStep over explicit (m, n_steps, B, ...) batches: vmapped
     ClientUpdate then the Pallas-backed server aggregation. This is the
@@ -177,19 +179,37 @@ def build_simulation_round_step(
     pre-strategy inline form (aggregate the client params directly; the
     identity update with no delta round-trip) — bit-for-bit the historical
     behavior, and the baseline for the ``round_engine_strategy`` overhead
-    benchmark."""
+    benchmark.
+
+    ``clients_per_chunk=1``: the cohort runs one client at a time, a
+    ``lax.scan`` over its m clients, for models whose m client copies do
+    not fit at once (docs/engine.md "Chunked cohorts"). The carry holds a
+    running ``accum_dtype`` sum of the example-share-weighted deltas per
+    leaf, so no (m, N) stack is ever built; the mean delta then goes to
+    ``strategy`` (FedAvg where None). ``ExperimentSpec`` admits it on the
+    unsharded lane only.
+
+    The metrics are ``{"loss"}`` plus, for a model whose loss reports
+    ``"counters"`` in its aux dict, each counter's mean over the cohort's
+    real steps."""
     interpret = default_interpret() if interpret is None else interpret
+    if clients_per_chunk is not None:
+        return partial(_chunked_round_step, loss_fn, strategy or FedAvg(),
+                       accum_dtype)
 
     def round_step(state: RoundState, rb: RoundBatch):
         with jax.named_scope("fedavg.client_update"):
             upd = jax.vmap(
                 lambda b, msk: client_update(loss_fn, state.params, b, msk,
-                                             rb.lr)
+                                             rb.lr, with_counters=True)
             )
-            client_params, losses = upd(rb.data, rb.step_mask)
-            loss = masked_weighted_loss(losses, rb.step_mask,
-                                        rb.client_weights,
-                                        axis_name=axis_name)
+            client_params, losses, counters = upd(rb.data, rb.step_mask)
+            metrics = {"loss": masked_weighted_loss(
+                losses, rb.step_mask, rb.client_weights, axis_name=axis_name)}
+            if counters:
+                metrics.update(_counter_means(
+                    counter_sums(counters, rb.step_mask),
+                    jnp.sum(rb.step_mask), axis_name))
         if strategy is None:
             with jax.named_scope("fedavg.aggregate"):
                 new_params = server_aggregate(
@@ -199,7 +219,7 @@ def build_simulation_round_step(
                     accum_dtype=accum_dtype,
                     axis_name=axis_name,
                 )
-            return state._replace(params=new_params), {"loss": loss}
+            return state._replace(params=new_params), metrics
         with jax.named_scope("fedavg.aggregate"):
             deltas = jax.tree.map(
                 lambda c, p: (c - p).astype(jnp.float32),
@@ -216,11 +236,63 @@ def build_simulation_round_step(
             outer, new_params = strategy.apply(
                 state.outer_state, state.params, agg_delta
             )
-        return state._replace(params=new_params, outer_state=outer), {
-            "loss": loss
-        }
+        return state._replace(params=new_params, outer_state=outer), metrics
 
     return round_step
+
+
+def _counter_means(sums, n_steps, axis_name=None):
+    """Counter sums over real steps -> means a step, finished over the
+    client axis where the cohort is sharded."""
+    if axis_name is not None:
+        sums = jax.lax.psum(sums, axis_name)
+        n_steps = jax.lax.psum(n_steps, axis_name)
+    return jax.tree.map(lambda s: s / jnp.maximum(n_steps, 1.0), sums)
+
+
+def _chunked_round_step(loss_fn, strategy, accum_dtype, state: RoundState,
+                        rb: RoundBatch):
+    """The round with the cohort run one client at a time (see
+    :func:`build_simulation_round_step`). Step ``j`` of the scan trains
+    cohort slot ``j`` on the batches assembled under that slot's keys, so
+    the round trains on the same batches as the vmapped one. The client is
+    unbatched: a model with layers that have no batching rule (grouped
+    matmuls) runs too, and no stacked copy of the weights is made. The
+    weighted mean delta is ``sum_k (n_k / n) * delta_k`` summed client by
+    client in ``accum_dtype``; the loss metric is ``masked_weighted_loss``'s
+    over the per-client losses."""
+    share = rb.client_weights / jnp.sum(rb.client_weights)
+
+    def client(acc, inp):
+        data, mask, w = inp
+        with jax.named_scope("fedavg.client_update"):
+            cp, losses, counters = client_update(
+                loss_fn, state.params, data, mask, rb.lr, with_counters=True)
+            sums = counter_sums(
+                jax.tree.map(lambda a: a[None], counters), mask[None])
+        with jax.named_scope("fedavg.aggregate"):
+            acc = jax.tree.map(
+                lambda a, c, p: a + w.astype(accum_dtype)
+                * (c - p).astype(accum_dtype),
+                acc, cp, state.params)
+        return acc, (losses, sums)
+
+    acc0 = jax.tree.map(lambda p: jnp.zeros(p.shape, accum_dtype),
+                        state.params)
+    agg_delta, (losses, sums) = jax.lax.scan(
+        client, acc0, (rb.data, rb.step_mask, share))
+    with jax.named_scope("fedavg.client_update"):
+        metrics = {"loss": masked_weighted_loss(
+            losses, rb.step_mask, rb.client_weights)}
+        if sums:
+            metrics.update(_counter_means(
+                jax.tree.map(lambda s: jnp.sum(s, axis=0), sums),
+                jnp.sum(rb.step_mask)))
+    with jax.named_scope("fedavg.apply"):
+        outer, new_params = strategy.apply(
+            state.outer_state, state.params, agg_delta
+        )
+    return state._replace(params=new_params, outer_state=outer), metrics
 
 
 # ---------------------------------------------------------------------------
@@ -243,6 +315,20 @@ class RoundRecord:
     # of each replica's L2 distance to the node-mean parameter vector
     # (docs/topology.md). None on the star lanes.
     consensus: Optional[float] = None
+    # The model's counters (its loss's aux "counters"), each the cohort's
+    # mean over its real steps; None for a model that reports none.
+    counters: Optional[Dict[str, List[float]]] = None
+
+
+def record_counters(metrics) -> Optional[Dict[str, List[float]]]:
+    """A round's host metrics less the loss, as lists for the record."""
+    out = {k: np.atleast_1d(np.asarray(v, np.float64)).tolist()
+           for k, v in metrics.items() if k != "loss"}
+    return out or None
+
+
+def _host_metrics(metrics) -> Dict[str, np.ndarray]:
+    return {k: np.asarray(v) for k, v in jax.device_get(metrics).items()}
 
 
 def _monotone_crossing(curve, target: float) -> Optional[float]:
@@ -374,6 +460,7 @@ class RoundEngine:
         pool_shard_clients: int = 1024,
         pool_dir=None,
         prefetch: int = 1,
+        clients_per_chunk: Optional[int] = None,
     ):
         self.loss_fn = loss_fn
         # Private copy: the round executables donate the params buffer
@@ -539,6 +626,7 @@ class RoundEngine:
                 strategy=self.strategy,
                 interpret=self.interpret,
                 accum_dtype=jnp.dtype(accum_dtype),
+                clients_per_chunk=clients_per_chunk,
             )
             # Donate the params/strategy carries like the device lane.
             # (The staged cohort buffers are dead after their round too,
@@ -611,6 +699,7 @@ class RoundEngine:
             interpret=self.interpret,
             accum_dtype=jnp.dtype(accum_dtype),
             axis_name=client_axis if mesh is not None else None,
+            clients_per_chunk=clients_per_chunk,
         )
         body = partial(_engine_round, loss_fn, **shape_kw)
         sbody = partial(
@@ -841,6 +930,7 @@ class RoundEngine:
             pool=getattr(ex, "pool", "auto"),
             pool_shard_clients=getattr(ex, "pool_shard_clients", 1024),
             prefetch=getattr(ex, "prefetch", 1),
+            clients_per_chunk=getattr(ex, "clients_per_chunk", None),
         )
 
     # -- introspection ----------------------------------------------------
@@ -1112,7 +1202,7 @@ class RoundEngine:
             )
         x, y, w, spe_k, key, lr = b["dev"]
         with jax.profiler.TraceAnnotation("fedavg.dispatch"):
-            self.params, self.outer_state, loss = self._staged_round_jit(
+            self.params, self.outer_state, metrics = self._staged_round_jit(
                 self.params, self.outer_state, x, y, w, spe_k, key, lr
             )
         self.round_idx += 1
@@ -1121,7 +1211,7 @@ class RoundEngine:
             # so this shard read + staging overlaps the round's compute.
             with jax.profiler.TraceAnnotation("fedavg.prepare"):
                 self._prefetched = self._prepare_round(self.round_idx)
-        return {"loss": loss}
+        return metrics
 
     def _superstep_streamed(self, r: int) -> np.ndarray:
         with jax.profiler.TraceAnnotation("fedavg.prepare"):
@@ -1131,7 +1221,7 @@ class RoundEngine:
             )
         xs, ys, ws, spes, keys, lrs = b["dev"]
         with jax.profiler.TraceAnnotation("fedavg.dispatch"):
-            self.params, self.outer_state, losses = (
+            self.params, self.outer_state, metrics = (
                 self._staged_superstep_jit(
                     self.params, self.outer_state, xs, ys, ws, spes, keys,
                     lrs,
@@ -1145,11 +1235,11 @@ class RoundEngine:
             with jax.profiler.TraceAnnotation("fedavg.prepare"):
                 self._prefetched = self._prepare_chunk(self.round_idx, r)
         with jax.profiler.TraceAnnotation("fedavg.sync"):
-            return np.asarray(jax.device_get(losses))
+            return _host_metrics(metrics)
 
     def round(self) -> Dict[str, float]:
-        """One synchronous round; returns {'loss': ...} (plus
-        'consensus' on the gossip lane)."""
+        """One synchronous round; returns {'loss': ...} (plus the model's
+        counters, or 'consensus' on the gossip lane), on the device."""
         if self.topology is not None:
             return self._round_gossip()
         if self.pool_kind == "streamed":
@@ -1157,12 +1247,12 @@ class RoundEngine:
         with jax.profiler.TraceAnnotation("fedavg.prepare"):
             ids, valid, key, lr = self._next_round_inputs()
         with jax.profiler.TraceAnnotation("fedavg.dispatch"):
-            self.params, self.outer_state, loss = self._round_jit(
+            self.params, self.outer_state, metrics = self._round_jit(
                 self.params, self.outer_state, self._x, self._y,
                 self._counts, self._spe, ids, valid, key, lr,
             )
         self.round_idx += 1
-        return {"loss": loss}
+        return metrics
 
     def _round_gossip(self) -> Dict[str, float]:
         """One gossip round: every node runs its local-SGD phase on its
@@ -1209,9 +1299,9 @@ class RoundEngine:
             )
         return R
 
-    def _superstep(self, r: int) -> np.ndarray:
-        """Advance r rounds in ONE dispatch; returns the (r,) per-round
-        losses, synced. The lr schedule is precomputed host-side (handles
+    def _superstep(self, r: int) -> Dict[str, np.ndarray]:
+        """Advance r rounds in ONE dispatch; returns the per-round metrics
+        (``"loss"``: (r,)), synced. The lr schedule is precomputed host-side (handles
         both scalar-decay and callable cfg.lr), the cohort key rides in the
         scan carry, and params + key buffers are donated. On the streamed
         lane the scan consumes pre-staged cohorts instead (the host
@@ -1226,7 +1316,7 @@ class RoundEngine:
             if self._rep is not None:
                 lrs = jax.device_put(lrs, self._rep)
         with jax.profiler.TraceAnnotation("fedavg.dispatch"):
-            self.params, self.outer_state, self.sample_key, losses = (
+            self.params, self.outer_state, self.sample_key, metrics = (
                 self._superstep_jit(
                     self.params, self.outer_state, self.sample_key, self._x,
                     self._y, self._counts, self._spe, lrs,
@@ -1236,9 +1326,9 @@ class RoundEngine:
         # sanctioned transfer, and explicitness keeps it legal under
         # transfer_guard("disallow") on guarded backends.
         with jax.profiler.TraceAnnotation("fedavg.sync"):
-            losses = np.asarray(jax.device_get(losses))
+            metrics = _host_metrics(metrics)
         self.round_idx += r
-        return losses
+        return metrics
 
     def run(
         self,
@@ -1307,16 +1397,18 @@ class RoundEngine:
             with jax.profiler.StepTraceAnnotation(
                 "fedavg.superstep", step_num=self.round_idx
             ):
-                losses = self._superstep(r)  # blocks on the chunk's outputs
+                metrics = self._superstep(r)  # blocks on the chunk's outputs
             chunk_s = time.perf_counter() - t0
             done += r
             for j in range(r):
                 self.history.records.append(RoundRecord(
                     round=self.round_idx - r + j + 1,
-                    train_loss=float(losses[j]),
+                    train_loss=float(metrics["loss"][j]),
                     # Amortized accounting: the host observes one synced
                     # chunk, so each round is charged chunk_time / r.
                     wall_s=chunk_s / r,
+                    counters=record_counters(
+                        {k: v[j] for k, v in metrics.items()}),
                 ))
             rec = self.history.records[-1]
             # Evaluate whenever this chunk CROSSED an eval point (not only
@@ -1681,7 +1773,7 @@ def _assemble_cohort_batches(px, py, ids, w, spe_k, key, *, E, spe, B,
 def _engine_round(
     loss_fn, params, outer, px, py, counts, spe_arr, ids, valid, key, lr,
     *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
-    accum_dtype, axis_name=None,
+    accum_dtype, axis_name=None, clients_per_chunk=None,
 ):
     # Under shard_map ``ids``/``valid`` are this shard's (m/D,) cohort
     # slice; the shard's global slot offset keys all per-client randomness
@@ -1701,21 +1793,24 @@ def _engine_round(
     return _apply_round_step(
         loss_fn, params, outer, batch, mask, w, key, lr, codec=codec,
         strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
-        axis_name=axis_name,
+        axis_name=axis_name, clients_per_chunk=clients_per_chunk,
     )
 
 
 def _apply_round_step(
     loss_fn, params, outer, batch, mask, w, key, lr,
     *, codec, strategy, interpret, accum_dtype, axis_name=None,
+    clients_per_chunk=None,
 ):
     """The server half every lane shares from the assembled cohort on:
-    plain or compressed round step, strategy threading, loss metric. One
-    definition so the device and streamed pool backends cannot drift."""
+    plain, chunked or compressed round step, strategy threading, the
+    round's metrics (``{"loss"}`` and any model counters). One definition
+    so the device and streamed pool backends cannot drift."""
     if codec is None:
         step = build_simulation_round_step(
             loss_fn, interpret=interpret, accum_dtype=accum_dtype,
             axis_name=axis_name, strategy=strategy,
+            clients_per_chunk=clients_per_chunk,
         )
         codec_key = None
     else:
@@ -1733,13 +1828,13 @@ def _apply_round_step(
         RoundState(params, outer_state=outer),
         RoundBatch(batch, mask, w, lr=lr, key=codec_key),
     )
-    return state.params, state.outer_state, metrics["loss"]
+    return state.params, state.outer_state, metrics
 
 
 def _engine_round_staged(
     loss_fn, params, outer, cx, cy, w, spe_k, key, lr,
     *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
-    accum_dtype,
+    accum_dtype, clients_per_chunk=None,
 ):
     """The streamed-pool round body: identical to :func:`_engine_round`
     but the cohort's (m, n_pad, F) rows arrive pre-staged (host shard reads,
@@ -1756,13 +1851,14 @@ def _engine_round_staged(
     return _apply_round_step(
         loss_fn, params, outer, batch, mask, w, key, lr, codec=codec,
         strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
+        clients_per_chunk=clients_per_chunk,
     )
 
 
 def _engine_superstep_staged(
     loss_fn, params, outer, cxs, cys, ws, spes, keys, lrs,
     *, E, spe, B, feature_shape, has_labels, codec, strategy, interpret,
-    accum_dtype,
+    accum_dtype, clients_per_chunk=None,
 ):
     """The streamed twin of :func:`_engine_superstep`: R pre-staged cohorts
     scanned in one donated executable. The cohort draw already happened on
@@ -1775,24 +1871,25 @@ def _engine_superstep_staged(
     def one_round(carry, inp):
         p, o = carry
         cx, cy, w, spe_k, key, lr = inp
-        new_p, new_o, loss = _engine_round_staged(
+        new_p, new_o, metrics = _engine_round_staged(
             loss_fn, p, o, cx, cy, w, spe_k, key, lr,
             E=E, spe=spe, B=B, feature_shape=feature_shape,
             has_labels=has_labels, codec=codec,
             strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
+            clients_per_chunk=clients_per_chunk,
         )
-        return (new_p, new_o), loss
+        return (new_p, new_o), metrics
 
-    (params, outer), losses = jax.lax.scan(
+    (params, outer), metrics = jax.lax.scan(
         one_round, (params, outer), (cxs, cys, ws, spes, keys, lrs)
     )
-    return params, outer, losses
+    return params, outer, metrics
 
 
 def _engine_superstep(
     loss_fn, params, outer, key, px, py, counts, spe_arr, lrs,
     *, K, m, shards, E, spe, B, feature_shape, has_labels, codec, strategy,
-    interpret, accum_dtype, axis_name=None,
+    interpret, accum_dtype, axis_name=None, clients_per_chunk=None,
 ):
     """R = len(lrs) full rounds fused into one ``lax.scan``: per round, the
     carry key splits into (cohort draw, data/codec key, next carry) exactly
@@ -1801,7 +1898,7 @@ def _engine_superstep(
     ``_engine_round`` — the identical per-round body, codec, server
     strategy and all — runs on it. The strategy state rides in the scan
     carry next to the params. Returns (params, strategy state, advanced
-    key, (R,) per-round losses).
+    key, per-round metrics: each an (R, ...) stack).
 
     Under cohort sharding this whole function sits INSIDE the shard_map:
     every shard replays the (replicated) cohort draw and slices its own
@@ -1823,19 +1920,19 @@ def _engine_superstep(
                 ids = jax.lax.dynamic_slice_in_dim(ids, d * m_local, m_local)
                 valid = jax.lax.dynamic_slice_in_dim(valid, d * m_local,
                                                      m_local)
-        new_p, new_o, loss = _engine_round(
+        new_p, new_o, metrics = _engine_round(
             loss_fn, p, o, px, py, counts, spe_arr, ids, valid, k_data, lr,
             E=E, spe=spe, B=B, feature_shape=feature_shape,
             has_labels=has_labels, codec=codec,
             strategy=strategy, interpret=interpret, accum_dtype=accum_dtype,
-            axis_name=axis_name,
+            axis_name=axis_name, clients_per_chunk=clients_per_chunk,
         )
-        return (new_p, new_o, k_next), loss
+        return (new_p, new_o, k_next), metrics
 
-    (params, outer, key), losses = jax.lax.scan(
+    (params, outer, key), metrics = jax.lax.scan(
         one_round, (params, outer, key), lrs
     )
-    return params, outer, key, losses
+    return params, outer, key, metrics
 
 
 # -- gossip executables (core.topology, docs/topology.md) -------------------

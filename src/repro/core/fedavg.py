@@ -76,6 +76,8 @@ def client_update(
     batches,
     step_mask,
     lr,
+    *,
+    with_counters: bool = False,
 ) -> Any:
     """ClientUpdate(k, w) — Algorithm 1, right column.
 
@@ -83,16 +85,36 @@ def client_update(
     the client's full E-epoch batch schedule. ``step_mask``: (n_steps,) 0/1
     float — padded steps (for vmap-ing ragged clients together) are no-ops.
     Plain SGD with fixed per-round lr, as in the paper.
+
+    Returns ``(params, losses)``; with ``with_counters`` also the per-step
+    counters the model reports under ``"counters"`` in its loss's aux dict
+    (leaves (n_steps, ...); ``{}`` for a model that reports none).
     """
 
     def one_step(w, inp):
         batch, mask = inp
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(w, batch)
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(w, batch)
         w = jax.tree.map(lambda p, g: p - lr * mask * g, w, grads)
+        if with_counters:
+            counters = aux.get("counters", {}) if isinstance(aux, dict) else {}
+            return w, (loss, counters)
         return w, loss
 
-    params, losses = jax.lax.scan(one_step, params, (batches, step_mask))
-    return params, losses
+    params, out = jax.lax.scan(one_step, params, (batches, step_mask))
+    if with_counters:
+        losses, counters = out
+        return params, losses, counters
+    return params, out
+
+
+def counter_sums(counters, step_mask):
+    """Each counter summed over the real (unmasked) steps of every client:
+    ``counters`` leaves are (m, n_steps, ...), ``step_mask`` (m, n_steps).
+    Divided by ``step_mask.sum()`` this is the cohort's mean a step."""
+    def one(c):
+        mask = step_mask.reshape(step_mask.shape + (1,) * (c.ndim - 2))
+        return jnp.sum(c * mask, axis=(0, 1))
+    return jax.tree.map(one, counters)
 
 
 def masked_weighted_loss(losses, step_mask, client_weights, *, axis_name=None):

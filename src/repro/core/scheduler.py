@@ -120,7 +120,7 @@ class RoundScheduler:
         """The per-round barrier loop, verbatim from the pre-scheduler
         ``RoundEngine.run`` — plus, when a LatencyModel is present,
         per-round simulated duration and dropout ghost-masking."""
-        from repro.core.engine import RoundRecord
+        from repro.core.engine import RoundRecord, record_counters
 
         eng = self.engine
         lat_rng = self.model.init_rng() if self.model is not None else None
@@ -131,6 +131,7 @@ class RoundScheduler:
         for i in range(n_rounds):
             t0 = time.perf_counter()
             sim_s = 0.0
+            counters = None
             with jax.profiler.StepTraceAnnotation(
                 "fedavg.round", step_num=eng.round_idx
             ):
@@ -143,7 +144,9 @@ class RoundScheduler:
                     # keeps the D2H read explicit, so the loop stays legal
                     # under transfer_guard("disallow") on guarded backends.
                     with jax.profiler.TraceAnnotation("fedavg.sync"):
-                        loss = float(jax.device_get(metrics["loss"]))
+                        host = jax.device_get(metrics)
+                    loss = float(host["loss"])
+                    counters = record_counters(host)
                 else:
                     loss, sim_s = self._latency_round(lat_rng, speed)
             rec = RoundRecord(
@@ -151,6 +154,7 @@ class RoundScheduler:
                 train_loss=loss,
                 wall_s=time.perf_counter() - t0,
                 sim_s=sim_s,
+                counters=counters,
             )
             # i, not round_idx, for the last-round check: round_idx is
             # cumulative across run() calls, so a second run(n) would never
@@ -196,13 +200,13 @@ class RoundScheduler:
             eng.round_idx += 1
             return float("nan"), sim_s
         with jax.profiler.TraceAnnotation("fedavg.dispatch"):
-            eng.params, eng.outer_state, loss = eng._round_jit(
+            eng.params, eng.outer_state, metrics = eng._round_jit(
                 eng.params, eng.outer_state, eng._x, eng._y, eng._counts,
                 eng._spe, ids, valid, key, lr,
             )
         eng.round_idx += 1
         with jax.profiler.TraceAnnotation("fedavg.sync"):
-            return float(jax.device_get(loss)), sim_s
+            return float(jax.device_get(metrics["loss"])), sim_s
 
     # ------------------------------------------------------------------
     # buffered-async schedule

@@ -100,10 +100,11 @@ def layer_plan(cfg: ModelConfig, *, decoder: bool = True) -> List[LayerSpec]:
 
 def _dense_ff(cfg: ModelConfig) -> int:
     """Dense-layer FFN width for MoE archs' leading dense layers (the
-    DeepSeek model cards use a wider dense FFN than the per-expert width)."""
+    DeepSeek model cards use a wider dense FFN than the per-expert width):
+    ``moe.dense_d_ff`` where the config gives it, else the per-expert width
+    times the experts a token reaches (routed top-k plus shared)."""
     mo = cfg.moe
-    approx = mo.d_ff * (mo.topk + mo.n_shared_experts)
-    return approx
+    return mo.dense_d_ff or mo.d_ff * (mo.topk + mo.n_shared_experts)
 
 
 @dataclasses.dataclass
@@ -189,6 +190,7 @@ def _sublayer_apply(
 ):
     new_cache: Dict[str, Any] = {}
     aux = 0.0
+    held = None
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if spec.mixer == "attn":
         out, mc, _ = attention_apply(
@@ -196,10 +198,12 @@ def _sublayer_apply(
             mode=mode, window=window,
         )
     elif spec.mixer == "mla":
-        out, mc, _ = mla_apply(
-            p["mixer"], cfg, h, positions=positions, cache=None if cache is None else cache["mixer"],
-            mode=mode, window=window,
-        )
+        with jax.named_scope("model.mla"):
+            out, mc, _ = mla_apply(
+                p["mixer"], cfg, h, positions=positions,
+                cache=None if cache is None else cache["mixer"], mode=mode,
+                window=window,
+            )
     elif spec.mixer == "mamba":
         out, mc = ssm_mod.mamba_apply(
             p["mixer"], cfg, h, cache=None if cache is None else cache["mixer"], mode=mode
@@ -226,13 +230,15 @@ def _sublayer_apply(
         x = x + out
     if spec.ffn == "mlp":
         h = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        x = x + mlp_apply(p["ffn"], h, cfg.act)
+        with jax.named_scope("model.ffn"):
+            x = x + mlp_apply(p["ffn"], h, cfg.act)
     elif spec.ffn == "moe":
         h = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        out, moe_aux = moe_apply(p["ffn"], cfg, h, cfg.act)
+        with jax.named_scope("model.moe"):
+            out, moe_aux, held = moe_apply(p["ffn"], cfg, h, cfg.act)
         aux = aux + moe_aux
         x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, held
 
 
 # ---------------------------------------------------------------------------
@@ -285,8 +291,12 @@ def _stack_apply(
     window,
     memory=None,
 ):
+    """Returns ``(x, new_caches, aux, held)``: ``held`` is the (n_moe,)
+    count of each MoE layer's assignments to the experts it holds, in layer
+    order (empty without MoE layers)."""
     new_caches = []
     aux_total = 0.0
+    held_all = []
     for si, seg in enumerate(segments):
         p_seg = stack_params[si]
         c_seg = None if caches is None else caches[si]
@@ -295,8 +305,9 @@ def _stack_apply(
             h, aux = carry
             p_rep, c_rep = xs
             nc_rep = {}
+            held_rep = []
             for j in range(len(seg.specs)):
-                h, nc, a = _sublayer_apply(
+                h, nc, a, held = _sublayer_apply(
                     p_rep[f"sub{j}"],
                     seg.specs[j],
                     cfg,
@@ -309,29 +320,38 @@ def _stack_apply(
                 )
                 nc_rep[f"sub{j}"] = nc
                 aux = aux + a
-            return (h, aux), nc_rep
+                if held is not None:
+                    held_rep.append(held)
+            return (h, aux), (nc_rep, jnp.stack(held_rep) if held_rep else None)
 
         if cfg.remat:
             body = jax.checkpoint(body)
 
         if cfg.scan_layers and seg.repeats > 1:
-            (x, aux_total), nc = jax.lax.scan(
+            (x, aux_total), (nc, held) = jax.lax.scan(
                 body, (x, aux_total), (p_seg, c_seg)
             )
+            if held is not None:
+                held_all.append(held.reshape(-1))
         else:
             ncs = []
             for r in range(seg.repeats):
                 p_rep = jax.tree.map(lambda a: a[r], p_seg)
                 c_rep = None if c_seg is None else jax.tree.map(lambda a: a[r], c_seg)
-                (x, aux_total), nc_rep = body((x, aux_total), (p_rep, c_rep))
+                (x, aux_total), (nc_rep, held) = body(
+                    (x, aux_total), (p_rep, c_rep))
                 ncs.append(nc_rep)
+                if held is not None:
+                    held_all.append(held)
             nc = (
                 jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
                 if ncs and any(jax.tree.leaves(n) for n in ncs)
                 else {}
             )
         new_caches.append(nc)
-    return x, new_caches, aux_total
+    held = (jnp.concatenate(held_all) if held_all
+            else jnp.zeros((0,), jnp.int32))
+    return x, new_caches, aux_total, held
 
 
 # ---------------------------------------------------------------------------
@@ -444,7 +464,7 @@ class TransformerLM:
         pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         # Bidirectional: reuse the attn stack with causal off via window trick?
         # Cleanest: temporarily run attention non-causally.
-        x, _, _ = _stack_apply(
+        x, _, _, _ = _stack_apply(
             params["encoder"]["layers"],
             self.enc_segments,
             dataclasses.replace(cfg, sliding_window=0),
@@ -458,8 +478,16 @@ class TransformerLM:
 
     # -- forward ------------------------------------------------------------
     def forward(self, params, batch, *, mode, caches=None, window=0):
+        x, new_caches, aux, _ = self._forward(
+            params, batch, mode=mode, caches=caches, window=window)
+        return x, new_caches, aux
+
+    def _forward(self, params, batch, *, mode, caches=None, window=0):
+        """:meth:`forward`, plus each MoE layer's count of assignments to
+        its held experts."""
         cfg = self.cfg
-        x = self._embed_in(params, batch)
+        with jax.named_scope("model.embed"):
+            x = self._embed_in(params, batch)
         B, S, _ = x.shape
         offset = batch.get("pos_offset", 0)
         positions = self._positions(batch, S, offset)
@@ -470,7 +498,7 @@ class TransformerLM:
             if self.enc_segments and mode != "decode"
             else None
         )
-        x, new_caches, aux = _stack_apply(
+        x, new_caches, aux, held = _stack_apply(
             params["layers"],
             self.segments,
             cfg,
@@ -481,16 +509,32 @@ class TransformerLM:
             window=window,
             memory=memory,
         )
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return x, new_caches, aux
+        with jax.named_scope("model.head"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return x, new_caches, aux, held
 
     # -- entry points ---------------------------------------------------------
     def train_loss(self, params, batch):
+        """``(ce + aux, metrics)``, where ``moe.seq_aux`` gives aux its
+        gradient but not its value. Where the MoE layers hold a share of
+        the experts, ``metrics["counters"]["moe_held_assignments"]`` is each
+        MoE layer's count of (token, expert) assignments that landed on its
+        held experts."""
         cfg = self.cfg
-        hidden, _, aux = self.forward(params, batch, mode="train",
-                                      window=cfg.sliding_window)
-        ce = chunked_cross_entropy(hidden, self._head(params), batch["labels"], cfg.ce_chunk)
-        return ce + aux, {"ce": ce, "aux": aux}
+        hidden, _, aux, held = self._forward(params, batch, mode="train",
+                                             window=cfg.sliding_window)
+        with jax.named_scope("model.head"):
+            ce = chunked_cross_entropy(hidden, self._head(params),
+                                       batch["labels"], cfg.ce_chunk)
+        metrics = {"ce": ce, "aux": aux}
+        if cfg.moe is not None and cfg.moe.n_held:
+            metrics["counters"] = {
+                "moe_held_assignments": held.astype(jnp.float32)}
+        if cfg.moe is not None and cfg.moe.seq_aux:
+            # As DeepSeek-V2's AddAuxiliaryLoss: the balance loss adds its
+            # gradient; the loss reads the cross-entropy alone.
+            return ce + (aux - jax.lax.stop_gradient(aux)), metrics
+        return ce + aux, metrics
 
     def loss(self, params, batch):
         """(loss, aux-dict) signature compatible with core.fedavg."""
@@ -548,3 +592,44 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
     n_moe_layers = sum(1 for s in layer_plan(cfg) if s.ffn == "moe")
     inactive = (mo.n_experts - mo.topk) * per_expert * n_moe_layers
     return total - inactive
+
+
+# ---------------------------------------------------------------------------
+# The registry's factory (specs.MODELS["transformer"])
+# ---------------------------------------------------------------------------
+
+
+def transformer(preset: str, **overrides):
+    """A repo preset (``repro.configs.get_config(preset)``) as the federated
+    engine's ``Model(init, apply, loss)``.
+
+    ``overrides`` replace ``ModelConfig`` fields; ``moe``, ``mla`` and
+    ``rope_scaling`` take a dict of the fields to replace in that group
+    (e.g. ``moe={"n_held": 8, "held_first": 0}`` for one chip's share of
+    an expert-parallel layer). ``apply(params, tokens)`` gives float32
+    next-token logits ``(B, S, V)``; ``loss(params, (tokens, labels))`` is
+    the mean next-token cross-entropy plus the router aux loss (its
+    gradient only, with ``moe.seq_aux``), with ``{"ce", "aux"}`` (and the
+    held-expert counters) beside it."""
+    from repro.configs import get_config
+    from repro.models.paper import Model
+
+    cfg = get_config(preset)
+    for group in ("moe", "mla", "rope_scaling"):
+        if group in overrides:
+            fields = overrides.pop(group)
+            if getattr(cfg, group) is None:
+                raise ValueError(f"preset {preset!r} has no {group!r} group "
+                                 "to override")
+            overrides[group] = dataclasses.replace(getattr(cfg, group),
+                                                   **fields)
+    cfg = dataclasses.replace(cfg, **overrides)
+    lm = TransformerLM(cfg)
+
+    def apply(params, tokens):
+        hidden, _, _ = lm.forward(params, {"tokens": tokens}, mode="train",
+                                  window=cfg.sliding_window)
+        with jax.named_scope("model.head"):
+            return (hidden @ lm._head(params)).astype(jnp.float32)
+
+    return Model(lm.init, apply, lm.loss)

@@ -210,7 +210,14 @@ class ExecutionSpec:
     the packed pool would exceed ``data.pool.device_pool_budget()``).
     ``pool_shard_clients`` is the streamed store's clients-per-shard;
     ``prefetch`` enables double-buffered staging (0 disables, 1 stages the
-    next cohort/superstep chunk while the current one computes)."""
+    next cohort/superstep chunk while the current one computes).
+
+    ``clients_per_chunk`` (docs/engine.md "Chunked cohorts"): 1 runs the
+    round's ClientUpdate one client at a time, keeping a running weighted
+    sum of their deltas, for models whose m client copies do not fit at
+    once (None: the whole cohort in one vmap). Dense uploads on the
+    unsharded synchronous star lane only; ``ExperimentSpec`` refuses any
+    other value or lane."""
 
     mesh_axes: Optional[str] = None
     device_sampling: bool = False
@@ -220,6 +227,7 @@ class ExecutionSpec:
     pool: str = "auto"
     pool_shard_clients: int = 1024
     prefetch: int = 1
+    clients_per_chunk: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,6 +250,30 @@ class ExperimentSpec:
     # Run-length defaults for scripts/benchmarks (run() args still win).
     rounds: int = 100
     target_acc: Optional[float] = None
+
+    def __post_init__(self):
+        c = self.execution.clients_per_chunk
+        if c is None:
+            return
+        if c != 1:
+            raise ValueError(
+                f"spec {self.name!r} sets execution.clients_per_chunk={c}: "
+                "only chunks of one client are implemented (1, or None for "
+                "the whole cohort)"
+            )
+        taken = {
+            "codec": self.codec,
+            "topology": self.topology,
+            "async_spec": self.async_spec,
+            "execution.mesh_axes": self.execution.mesh_axes,
+        }
+        refused = sorted(k for k, v in taken.items() if v is not None)
+        if refused:
+            raise ValueError(
+                f"spec {self.name!r} sets execution.clients_per_chunk={c} "
+                f"with {refused}: the chunked cohort sums dense deltas of the "
+                "unsharded synchronous star round — drop one or the other"
+            )
 
     # -- builders ----------------------------------------------------------
 
@@ -325,6 +357,7 @@ def _models_registry() -> Dict[str, Any]:
         mnist_cnn,
         word_lstm,
     )
+    from repro.models.transformer import transformer
 
     return {
         "mnist_2nn": mnist_2nn,
@@ -332,6 +365,8 @@ def _models_registry() -> Dict[str, Any]:
         "cifar_cnn": cifar_cnn,
         "char_lstm": char_lstm,
         "word_lstm": word_lstm,
+        # A repo preset by name (repro.configs), with field overrides.
+        "transformer": transformer,
     }
 
 

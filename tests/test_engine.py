@@ -181,11 +181,11 @@ def test_engine_round_matches_reference_on_same_batches(rng):
     client_params, _ = upd(batch, mask)
     want = tree_weighted_mean(client_params, w)
 
-    got, _, loss = eng._round_jit(
+    got, _, metrics = eng._round_jit(
         eng.params, eng.outer_state, eng._x, eng._y, eng._counts, eng._spe,
         ids, valid, key, lr,
     )
-    assert np.isfinite(float(loss))
+    assert np.isfinite(float(metrics["loss"]))
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=1e-6)
 
@@ -264,13 +264,14 @@ def test_round_jit_donation_no_warning_and_unchanged(rng):
     ids, valid, key, lr = eng._next_round_inputs()
     args = (eng._x, eng._y, eng._counts, eng._spe, ids, valid, key, lr)
     # Undonated reference first — it leaves eng.params alive.
-    want, _, want_loss = jax.jit(eng._round_body)(
+    want, _, want_metrics = jax.jit(eng._round_body)(
         eng.params, eng.outer_state, *args
     )
     with warnings.catch_warnings():
         warnings.filterwarnings("error", message=".*[Dd]onat.*")
-        got, _, got_loss = eng._round_jit(eng.params, eng.outer_state, *args)
-    assert float(got_loss) == float(want_loss)
+        got, _, got_metrics = eng._round_jit(eng.params, eng.outer_state,
+                                             *args)
+    assert float(got_metrics["loss"]) == float(want_metrics["loss"])
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # and the donated input really was consumed (in-place server update)

@@ -11,6 +11,7 @@ The topology is described inside a module fixture (never at import, in a
 ``parametrize`` argument or in ``conftest.py``): only one process may load
 the TPU library, and test collection happens in every worker.
 """
+import numpy as np
 import pytest
 
 import jax
@@ -166,3 +167,110 @@ def test_batch_assembly_gathers_rows_for_v5e(one_chip):
     cost = cost.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["bytes accessed"] < 5e9, cost["bytes accessed"]
+
+
+def test_grouped_expert_matmul_computes_each_row_once_for_v5e(one_chip):
+    """The held experts' grouped matmul (``jax.lax.ragged_dot``) at the
+    DeepSeek-V2-Lite share's widths: all T*k = 49,152 assignments of a
+    B = 4 step sorted into 8 expert groups. The chip's lowering is one
+    Mosaic kernel whose cost is one pass over the rows, not one per group
+    (which a dense fallback would pay: 8x)."""
+    M, d, f, held = 49_152, 2048, 1408, 8
+    compiled = jax.jit(jax.lax.ragged_dot).lower(
+        jax.ShapeDtypeStruct((M, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((held, d, f), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] == pytest.approx(2.0 * M * d * f, rel=1e-3)
+
+
+def test_deepseek_share_round_fits_one_v5e(one_chip):
+    """The benchmark's ``dsv2lite_silo4_seq2048`` round (4 silos of 16
+    sequences of 2,048 tokens, B = 4, one silo a chunk) at the share's
+    published widths, 535 M float32 parameters: it compiles for one v5e,
+    its grouped matmuls become kernels, and its buffers fit the chip's
+    16 GiB."""
+    import json
+    from functools import partial
+    from pathlib import Path
+
+    from repro.core.engine import _engine_round
+    from repro.core.strategies import FedAvg
+    from repro.specs.spec import MODELS
+
+    conf = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "chip" / "configs" / "deepseek_v2_lite_share8.json")
+                      .read_text())
+    model = MODELS["transformer"](**conf["model"]["kwargs"])
+    K, n, S, B = 4, 16, 2048, 4
+    body = partial(
+        _engine_round, model.loss, E=1, spe=n // B, B=B, feature_shape=(S,),
+        has_labels=True, codec=None, strategy=FedAvg(), interpret=False,
+        accum_dtype=jnp.float32, clients_per_chunk=1,
+    )
+    on_chip = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    args = [on_chip((K, n, S), jnp.int32), on_chip((K, n, S), jnp.int32),
+            on_chip((K,), jnp.float32), on_chip((K,), jnp.int32),
+            on_chip((K,), jnp.int32), on_chip((K,), jnp.float32),
+            on_chip((2,), jnp.uint32), on_chip((), jnp.float32)]
+    compiled = jax.jit(body, donate_argnums=(0, 1)).lower(
+        params, (), *args).compile()
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.5 * 2**30, total
+
+
+def test_cohort_sharded_round_compiles_for_v5e_2x2(topo):
+    """The cohort-sharded lane's round (``shard_map`` over a four-chip
+    client axis, the Pallas aggregation in partial-sum mode finished by a
+    ``psum``) for the MNIST CNN at the paper's full cohort (m = 100, 25 a
+    chip, E = 5, B = 10) on a described 2x2 v5e host."""
+    from functools import partial
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.engine import _engine_round
+    from repro.core.strategies import FedAvg
+    from repro.models import mnist_cnn
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("clients",))
+        rep, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+        model = mnist_cnn()
+        body = jax.shard_map(
+            partial(_engine_round, model.loss, E=5, spe=60, B=10,
+                    feature_shape=(28, 28, 1), has_labels=True, codec=None,
+                    strategy=FedAvg(), interpret=False,
+                    accum_dtype=jnp.float32, axis_name="clients"),
+            mesh=mesh,
+            in_specs=(P(), P(), P(), P(), P(), P(), P("clients"),
+                      P("clients"), P(), P()),
+            out_specs=(P(), P(), P()), check_vma=False,
+        )
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        shapes = [((100, 600, 784), jnp.float32, rep),
+                  ((100, 600), jnp.int32, rep), ((100,), jnp.float32, rep),
+                  ((100,), jnp.int32, rep), ((100,), jnp.int32, split),
+                  ((100,), jnp.float32, split), ((2,), jnp.uint32, rep),
+                  ((), jnp.float32, rep)]
+        args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in shapes]
+        text = jax.jit(body).lower(params, (), *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert "all-reduce" in text
+    assert "tpu_custom_call" in text

@@ -51,7 +51,7 @@ def test_moe_matches_dense_oracle(rng):
     cfg = _moe_cfg()
     p = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jnp.asarray(rng.normal(size=(2, 8, 16)).astype(np.float32))
-    y, aux = moe_apply(p, cfg, x)
+    y, aux, _ = moe_apply(p, cfg, x)
     want = _moe_dense_oracle(p, cfg, x)
     np.testing.assert_allclose(y, want, atol=1e-5)
     assert float(aux) >= 0
@@ -62,7 +62,7 @@ def test_moe_capacity_drops_tokens(rng):
     cfg = _moe_cfg(cf=0.01)
     p = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jnp.asarray(rng.normal(size=(2, 8, 16)).astype(np.float32))
-    y, _ = moe_apply(p, cfg, x)
+    y, _, _ = moe_apply(p, cfg, x)
     dense = _moe_dense_oracle(p, cfg, x)
     assert float(jnp.sum(jnp.abs(y))) < float(jnp.sum(jnp.abs(dense)))
 
@@ -71,9 +71,9 @@ def test_moe_shared_expert_added(rng):
     cfg = _moe_cfg(shared=1)
     p = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jnp.asarray(rng.normal(size=(1, 8, 16)).astype(np.float32))
-    y, _ = moe_apply(p, cfg, x)
+    y, _, _ = moe_apply(p, cfg, x)
     cfg0 = _moe_cfg(shared=0)
-    y0, _ = moe_apply({k: v for k, v in p.items() if k != "shared"}, cfg0, x)
+    y0, _, _ = moe_apply({k: v for k, v in p.items() if k != "shared"}, cfg0, x)
     from repro.models.layers import mlp_apply
     np.testing.assert_allclose(y, y0 + mlp_apply(p["shared"], x, "silu"), atol=1e-5)
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -469,10 +470,12 @@ def moe_apply(p, cfg: ModelConfig, x, act="silu"):
     the layer is one chip's share of an expert-parallel layer: it computes
     only its held experts' part of the result, dropless
     (:func:`_held_experts`); otherwise every expert, through the capacity
-    buffer (:func:`_capacity_experts`). Returns ``(y, aux, held)``: the
-    output, the load-balance loss (0 when ``router_aux_weight`` is 0; per
-    sequence with ``seq_aux``) and
-    the number of (token, expert) assignments that landed on held experts.
+    buffer (:func:`_capacity_experts`). Returns ``(y, aux, counters)``:
+    the output, the load-balance loss (0 when ``router_aux_weight`` is 0;
+    per sequence with ``seq_aux``) and, with ``n_held`` set, the layer's
+    counters: the number of (token, expert) assignments that landed on held
+    experts (``moe_held_assignments``) and whether they ran in the compact
+    buffer (``moe_compact_buffer``); none otherwise.
     """
     mo = cfg.moe
     B, S, d = x.shape
@@ -509,16 +512,24 @@ def moe_apply(p, cfg: ModelConfig, x, act="silu"):
             aux = mo.router_aux_weight * E * jnp.sum(frac * probs_mean)
 
     if mo.n_held:
-        y, held = _held_experts(p, mo, xt, gate, expert_idx, act)
+        y, counters = _held_experts(p, mo, xt, gate, expert_idx, act)
     else:
         y = _capacity_experts(p, mo, xt, gate, expert_idx, act)
-        held = jnp.asarray(T * k, jnp.int32)
+        counters = {}
     y = y.reshape(B, S, d)
 
     if mo.n_shared_experts:
         with jax.named_scope("model.moe.shared"):
             y = y + mlp_apply(p["shared"], x, act)
-    return y, aux, held
+    return y, aux, counters
+
+
+def compact_rows(T: int, k: int, n_held: int, n_experts: int) -> int:
+    """Rows of the held experts' compact assignment buffer: twice the
+    ``T*k*n_held/n_experts`` assignments a uniform router sends to the
+    held experts, rounded up to a multiple of 128."""
+    rows = -(-2 * T * k * n_held // n_experts)
+    return -(-rows // 128) * 128
 
 
 def _held_experts(p, mo, xt, gate, expert_idx, act):
@@ -526,36 +537,104 @@ def _held_experts(p, mo, xt, gate, expert_idx, act):
     assignment to experts ``held_first .. held_first + n_held - 1`` is
     computed, by grouped matmuls (``jax.lax.ragged_dot``) over the
     assignments sorted by expert; assignments to experts held elsewhere sort
-    after them and contribute nothing here. The buffer holds all T*k
-    assignments, the most that can land here. Returns ``(y (T, d), held)``.
+    after them and contribute nothing here.
+
+    The experts run over the first C = :func:`compact_rows` sorted
+    assignments where the held count fits in them, and over all T*k, the
+    most that can land here, where it does not: the device picks the
+    branch from the count, so nothing is ever dropped. Where C is not under
+    T*k (a layer that holds half the experts or more, or so few assignments
+    that rounding up to 128 reaches T*k) only the T*k buffer
+    is built. Returns ``(y (T, d), counters)``: ``moe_held_assignments``,
+    the held count, and ``moe_compact_buffer``, 1 where the compact buffer
+    ran.
     """
-    T, d = xt.shape
+    T = xt.shape[0]
     k = expert_idx.shape[1]
     H = mo.n_held
     with jax.named_scope("model.moe.dispatch"):
         local = expert_idx.reshape(-1) - mo.held_first        # (T*k,)
-        mine = (local >= 0) & (local < H)
-        group = jnp.where(mine, local, H)
+        group = jnp.where((local >= 0) & (local < H), local, H)
         order = jnp.argsort(group, stable=True)               # held first
         sizes = jnp.sum(group[:, None] == jnp.arange(H)[None, :], axis=0,
                         dtype=jnp.int32)                      # (H,)
         held = jnp.sum(sizes)
-        # Rows past the held groups belong to no group: they are zeroed on
-        # the way in and out, so nothing the grouped matmuls leave there
-        # reaches the result or the gradients.
-        in_group = (jnp.arange(T * k) < held)[:, None]
-        rows = jnp.where(in_group, jnp.take(xt, order // k, axis=0), 0)
+    args = (xt, gate, p["we_i"], p["we_g"], p["we_o"], (order, sizes, held))
+    C = compact_rows(T, k, H, mo.n_experts)
+    if C < T * k:
+        y = _compact_or_full(*args, C, act)
+        compact = (held <= C).astype(jnp.int32)
+    else:
+        y = _held_rows(*args, n_rows=T * k, act=act)
+        compact = jnp.asarray(0, jnp.int32)
+    return y.astype(xt.dtype), {"moe_held_assignments": held,
+                                "moe_compact_buffer": compact}
+
+
+def _held_rows(xt, gate, we_i, we_g, we_o, route, *, n_rows, act):
+    """The held experts over the first ``n_rows`` sorted assignments, which
+    hold every held one. Rows past the held groups belong to no group: they
+    are zeroed on the way in and out, so nothing the grouped matmuls leave
+    there reaches the result or the gradients. Combine adds each row, times
+    its gate, into its token's row."""
+    order, sizes, held = route
+    T, d = xt.shape
+    k = gate.shape[1]
+    with jax.named_scope("model.moe.dispatch"):
+        first = order[:n_rows]
+        tok = first // k
+        in_group = (jnp.arange(n_rows) < held)[:, None]
+        rows = jnp.where(in_group, jnp.take(xt, tok, axis=0), 0)
     with jax.named_scope("model.moe.experts"):
-        h = jax.lax.ragged_dot(rows, p["we_i"], sizes)
-        h = h * _ACTS[act](jax.lax.ragged_dot(rows, p["we_g"], sizes))
-        out = jax.lax.ragged_dot(h, p["we_o"], sizes)         # (T*k, d)
+        h = jax.lax.ragged_dot(rows, we_i, sizes)
+        h = h * _ACTS[act](jax.lax.ragged_dot(rows, we_g, sizes))
+        out = jax.lax.ragged_dot(h, we_o, sizes)          # (n_rows, d)
     with jax.named_scope("model.moe.combine"):
-        out = jnp.where(in_group, out, 0)
-        inv = jnp.argsort(order)
-        contrib = jnp.take(out, inv, axis=0, unique_indices=True)
-        w = jnp.where(mine, gate.reshape(-1), 0.0).astype(out.dtype)
-        y = jnp.sum((contrib * w[:, None]).reshape(T, k, d), axis=1)
-    return y.astype(xt.dtype), held
+        w = gate.reshape(-1)[first].astype(out.dtype)
+        out = jnp.where(in_group, out, 0) * w[:, None]
+        return jnp.zeros((T, d), out.dtype).at[tok].add(out)
+
+
+def _on_held_count(n_rows, args, at):
+    """``at(n_rows)(*args)`` where the held count fits ``n_rows``, else
+    ``at(T*k)(*args)``: a conditional the device evaluates."""
+    T, k = args[1].shape
+    held = args[5][2]
+    return jax.lax.cond(held <= n_rows, at(n_rows), at(T * k), *args)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _compact_or_full(xt, gate, we_i, we_g, we_o, route, n_rows, act):
+    """:func:`_held_rows` over ``n_rows`` rows where the held count fits
+    them, else over all T*k. Its backward pass differentiates the branch
+    taken from the inputs alone: differentiated through the conditional,
+    JAX would join both branches' residuals, so the compact branch would
+    carry zeros of the T*k branch's (T*k, .) shapes."""
+    return _compact_or_full_fwd(xt, gate, we_i, we_g, we_o, route, n_rows,
+                                act)[0]
+
+
+def _compact_or_full_fwd(xt, gate, we_i, we_g, we_o, route, n_rows, act):
+    args = (xt, gate, we_i, we_g, we_o, route)
+    y = _on_held_count(n_rows, args,
+                       lambda n: partial(_held_rows, n_rows=n, act=act))
+    return y, args
+
+
+def _compact_or_full_bwd(n_rows, act, args, g):
+    def vjp_at(n):
+        def run(xt, gate, we_i, we_g, we_o, route):
+            layer = partial(_held_rows, route=route, n_rows=n, act=act)
+            return jax.vjp(layer, xt, gate, we_i, we_g, we_o)[1](g)
+        return run
+    grads = _on_held_count(n_rows, args, vjp_at)
+    # The barrier keeps XLA from moving what follows into the branches:
+    # there, the transpose of a stacked layer's weight slice (a pad to the
+    # stack's shape) made each branch write whole-stack weight gradients.
+    return (*jax.lax.optimization_barrier(grads), None)
+
+
+_compact_or_full.defvjp(_compact_or_full_fwd, _compact_or_full_bwd)
 
 
 def _capacity_experts(p, mo, xt, gate, expert_idx, act):

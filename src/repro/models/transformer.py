@@ -190,7 +190,7 @@ def _sublayer_apply(
 ):
     new_cache: Dict[str, Any] = {}
     aux = 0.0
-    held = None
+    counters = None
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if spec.mixer == "attn":
         out, mc, _ = attention_apply(
@@ -235,10 +235,10 @@ def _sublayer_apply(
     elif spec.ffn == "moe":
         h = rmsnorm(p["norm2"], x, cfg.norm_eps)
         with jax.named_scope("model.moe"):
-            out, moe_aux, held = moe_apply(p["ffn"], cfg, h, cfg.act)
+            out, moe_aux, counters = moe_apply(p["ffn"], cfg, h, cfg.act)
         aux = aux + moe_aux
         x = x + out
-    return x, new_cache, aux, held
+    return x, new_cache, aux, counters
 
 
 # ---------------------------------------------------------------------------
@@ -291,12 +291,12 @@ def _stack_apply(
     window,
     memory=None,
 ):
-    """Returns ``(x, new_caches, aux, held)``: ``held`` is the (n_moe,)
-    count of each MoE layer's assignments to the experts it holds, in layer
+    """Returns ``(x, new_caches, aux, counters)``: ``counters`` maps each
+    MoE layer counter of :func:`moe_apply` to its (n_moe,) values in layer
     order (empty without MoE layers)."""
     new_caches = []
     aux_total = 0.0
-    held_all = []
+    counters_all = []
     for si, seg in enumerate(segments):
         p_seg = stack_params[si]
         c_seg = None if caches is None else caches[si]
@@ -305,9 +305,9 @@ def _stack_apply(
             h, aux = carry
             p_rep, c_rep = xs
             nc_rep = {}
-            held_rep = []
+            counters_rep = []
             for j in range(len(seg.specs)):
-                h, nc, a, held = _sublayer_apply(
+                h, nc, a, counters = _sublayer_apply(
                     p_rep[f"sub{j}"],
                     seg.specs[j],
                     cfg,
@@ -320,38 +320,52 @@ def _stack_apply(
                 )
                 nc_rep[f"sub{j}"] = nc
                 aux = aux + a
-                if held is not None:
-                    held_rep.append(held)
-            return (h, aux), (nc_rep, jnp.stack(held_rep) if held_rep else None)
+                if counters is not None:
+                    counters_rep.append(counters)
+            return (h, aux), (nc_rep, _stack_counters(counters_rep))
+
+        def body_at(carry, p_seg, c_rep, r, body=body):
+            # Repeat r with its weights sliced inside the checkpoint, so
+            # that the slices are made where they are used, forward and
+            # backward, and not kept between the two as its inputs.
+            return body(carry, (jax.tree.map(lambda a: a[r], p_seg), c_rep))
 
         if cfg.remat:
             body = jax.checkpoint(body)
+            body_at = jax.checkpoint(body_at, static_argnums=(3,))
 
         if cfg.scan_layers and seg.repeats > 1:
-            (x, aux_total), (nc, held) = jax.lax.scan(
+            (x, aux_total), (nc, counters) = jax.lax.scan(
                 body, (x, aux_total), (p_seg, c_seg)
             )
-            if held is not None:
-                held_all.append(held.reshape(-1))
+            if counters is not None:
+                counters_all.append(
+                    jax.tree.map(lambda c: c.reshape(-1), counters))
         else:
             ncs = []
             for r in range(seg.repeats):
-                p_rep = jax.tree.map(lambda a: a[r], p_seg)
                 c_rep = None if c_seg is None else jax.tree.map(lambda a: a[r], c_seg)
-                (x, aux_total), (nc_rep, held) = body(
-                    (x, aux_total), (p_rep, c_rep))
+                (x, aux_total), (nc_rep, counters) = body_at(
+                    (x, aux_total), p_seg, c_rep, r)
                 ncs.append(nc_rep)
-                if held is not None:
-                    held_all.append(held)
+                if counters is not None:
+                    counters_all.append(counters)
             nc = (
                 jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
                 if ncs and any(jax.tree.leaves(n) for n in ncs)
                 else {}
             )
         new_caches.append(nc)
-    held = (jnp.concatenate(held_all) if held_all
-            else jnp.zeros((0,), jnp.int32))
-    return x, new_caches, aux_total, held
+    counters = (jax.tree.map(lambda *c: jnp.concatenate(c), *counters_all)
+                if counters_all else {})
+    return x, new_caches, aux_total, counters
+
+
+def _stack_counters(per_layer):
+    """Layer-by-layer counter dicts -> one dict of (n_layers,) arrays."""
+    if not per_layer:
+        return None
+    return jax.tree.map(lambda *c: jnp.stack(c), *per_layer)
 
 
 # ---------------------------------------------------------------------------
@@ -483,8 +497,8 @@ class TransformerLM:
         return x, new_caches, aux
 
     def _forward(self, params, batch, *, mode, caches=None, window=0):
-        """:meth:`forward`, plus each MoE layer's count of assignments to
-        its held experts."""
+        """:meth:`forward`, plus the MoE layers' counters
+        (:func:`_stack_apply`)."""
         cfg = self.cfg
         with jax.named_scope("model.embed"):
             x = self._embed_in(params, batch)
@@ -498,7 +512,7 @@ class TransformerLM:
             if self.enc_segments and mode != "decode"
             else None
         )
-        x, new_caches, aux, held = _stack_apply(
+        x, new_caches, aux, counters = _stack_apply(
             params["layers"],
             self.segments,
             cfg,
@@ -511,25 +525,26 @@ class TransformerLM:
         )
         with jax.named_scope("model.head"):
             x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return x, new_caches, aux, held
+        return x, new_caches, aux, counters
 
     # -- entry points ---------------------------------------------------------
     def train_loss(self, params, batch):
         """``(ce + aux, metrics)``, where ``moe.seq_aux`` gives aux its
         gradient but not its value. Where the MoE layers hold a share of
-        the experts, ``metrics["counters"]["moe_held_assignments"]`` is each
-        MoE layer's count of (token, expert) assignments that landed on its
-        held experts."""
+        the experts, ``metrics["counters"]`` holds, per MoE layer,
+        ``moe_held_assignments``, the count of (token, expert) assignments
+        that landed on its held experts, and ``moe_compact_buffer``, 1 where
+        they ran in the compact buffer."""
         cfg = self.cfg
-        hidden, _, aux, held = self._forward(params, batch, mode="train",
-                                             window=cfg.sliding_window)
+        hidden, _, aux, counters = self._forward(params, batch, mode="train",
+                                                 window=cfg.sliding_window)
         with jax.named_scope("model.head"):
             ce = chunked_cross_entropy(hidden, self._head(params),
                                        batch["labels"], cfg.ce_chunk)
         metrics = {"ce": ce, "aux": aux}
         if cfg.moe is not None and cfg.moe.n_held:
-            metrics["counters"] = {
-                "moe_held_assignments": held.astype(jnp.float32)}
+            metrics["counters"] = {k: c.astype(jnp.float32)
+                                   for k, c in counters.items()}
         if cfg.moe is not None and cfg.moe.seq_aux:
             # As DeepSeek-V2's AddAuxiliaryLoss: the balance loss adds its
             # gradient; the loss reads the cross-entropy alone.

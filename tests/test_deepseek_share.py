@@ -25,7 +25,9 @@ if str(ROOT) not in sys.path:
 
 from repro.configs import get_config  # noqa: E402
 from repro.core import RoundEngine  # noqa: E402
+from repro.models import layers  # noqa: E402
 from repro.models.layers import (  # noqa: E402
+    compact_rows,
     mla_softmax_gain,
     moe_apply,
     moe_init,
@@ -143,6 +145,8 @@ def test_engine_round_matches_the_reference():
     held = eng.history.records[0].counters["moe_held_assignments"]
     assert len(held) == 2
     assert all(0 < h <= 2 * SEQ * K for h in held)
+    assert all(h <= compact_rows(2 * SEQ, K, HELD, E) for h in held)
+    assert eng.history.records[0].counters["moe_compact_buffer"] == [1.0, 1.0]
 
 
 def test_shares_sum_to_the_uncut_layer():
@@ -165,6 +169,68 @@ def test_shares_sum_to_the_uncut_layer():
     m = REF._dims(_tiny_config(held=(0, E)))
     whole = REF._moe(p, x, m, jax.lax.Precision.HIGHEST)
     np.testing.assert_allclose(total, whole, atol=2e-6)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("routing", ["fits", "past_compact"])
+def test_compact_and_full_buffers_match_the_reference(monkeypatch, routing,
+                                                      remat):
+    """The held experts over the compact buffer, over the full T*k buffer
+    (the only one built when the compact size is not under T*k), and the
+    reference's dense evaluation give the same layer and the same
+    gradients for x, the router and the three expert weights. At 64 tokens
+    the compact buffer has 128 of the 192 assignment rows. A router biased
+    towards the held experts sends them every assignment, past the compact
+    buffer: the layer then takes the full buffer and says so in its
+    counter."""
+    T = 2 * SEQ
+    rows = compact_rows(T, K, HELD, E)
+    assert rows == 128 < T * K
+    cfg = _model_cfg(router_aux_weight=0.0)
+    p = moe_init(jax.random.PRNGKey(10), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(11), (2, SEQ, 64), jnp.float32)
+    if routing == "past_compact":
+        u = jnp.full((64,), 1 / 8.0)                          # unit norm
+        x = x + 4 * u
+        p["router"] = p["router"].at[:, FIRST:FIRST + HELD].add(4 * u[:, None])
+    cot = jax.random.normal(jax.random.PRNGKey(12), x.shape, jnp.float32)
+    m = REF._dims(_tiny_config())
+    m["alpha"] = 0.0
+
+    def grads(layer):
+        layer = jax.checkpoint(layer) if remat else layer
+        y, vjp = jax.vjp(layer, p, x)
+        return y, vjp(cot)
+
+    def program(p, x):
+        return moe_apply(p, cfg, x)[0]
+
+    y, g = grads(program)
+    counters = moe_apply(p, cfg, x)[2]
+    ref_y, ref_g = grads(lambda p, x: REF._moe(p, x, m,
+                                               jax.lax.Precision.HIGHEST))
+    monkeypatch.setattr(layers, "compact_rows", lambda *a: T * K)
+    full_y, full_g = grads(program)
+    assert int(moe_apply(p, cfg, x)[2]["moe_compact_buffer"]) == 0
+
+    held = int(counters["moe_held_assignments"])
+    if routing == "fits":
+        assert 0 < held <= rows
+        assert int(counters["moe_compact_buffer"]) == 1
+    else:
+        assert held == T * K
+        assert int(counters["moe_compact_buffer"]) == 0
+    np.testing.assert_allclose(y, full_y, rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(y, ref_y, rtol=1e-5, atol=2e-6)
+    (gp, gx), (fp, fx), (rp, rx) = g, full_g, ref_g
+    np.testing.assert_allclose(gx, fx, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(gx, rx, rtol=1e-4, atol=1e-6)
+    for name in ("router", "we_i", "we_g", "we_o"):
+        assert float(jnp.max(jnp.abs(rp[name]))) > 0, name
+        np.testing.assert_allclose(gp[name], fp[name], rtol=1e-5, atol=1e-7,
+                                   err_msg=name)
+        np.testing.assert_allclose(gp[name], rp[name], rtol=1e-4, atol=1e-6,
+                                   err_msg=name)
 
 
 def test_balance_loss_as_published():

@@ -11,6 +11,8 @@ The topology is described inside a module fixture (never at import, in a
 ``parametrize`` argument or in ``conftest.py``): only one process may load
 the TPU library, and test collection happens in every worker.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -185,6 +187,99 @@ def test_grouped_expert_matmul_computes_each_row_once_for_v5e(one_chip):
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["flops"] == pytest.approx(2.0 * M * d * f, rel=1e-3)
+
+
+def _hlo_computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and line.strip():
+            cur.append(line.strip())
+    return comps
+
+
+def _hlo_closure(comps, name):
+    """The lines of computation ``name`` and of every one it calls."""
+    seen, todo, lines = set(), [name], []
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen.add(n)
+        for line in comps[n]:
+            lines.append(line)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               line)
+            for group in re.findall(r"computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return lines
+
+
+def test_held_experts_compact_buffer_is_a_conditional_for_v5e(one_chip):
+    """The gradient of the DeepSeek-V2-Lite share's held-experts layer at
+    the benchmark cell's shapes (T = 8,192 tokens, top-6 of 64 experts, 8
+    held, hidden 2,048), under the preset's remat: the compact buffer of C
+    = 12,288 rows and the full one of T*k = 49,152 are the two branches of
+    a real conditional, in the forward and in the backward pass. The
+    compact branch makes no (T*k, .) buffer, and the ops of both branches
+    carry the dispatch, experts and combine scopes the per-layer readers
+    count."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.layers import compact_rows, moe_apply
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(cfg, param_dtype="float32",
+                              compute_dtype="float32",
+                              moe=dataclasses.replace(cfg.moe, n_held=8,
+                                                      held_first=0))
+    mo, d = cfg.moe, cfg.d_model
+    B, S, H = 4, 2048, mo.n_held
+    T, k = B * S, mo.topk
+    C = compact_rows(T, k, H, mo.n_experts)
+    assert (mo.n_experts, k, d, T * k, C) == (64, 6, 2048, 49_152, 12_288)
+    on_chip = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    params = {"router": on_chip((d, mo.n_experts)),
+              "we_i": on_chip((H, d, mo.d_ff)), "we_g": on_chip((H, d, mo.d_ff)),
+              "we_o": on_chip((H, mo.d_ff, d)),
+              "shared": {"wi": on_chip((d, mo.d_ff * mo.n_shared_experts)),
+                         "wg": on_chip((d, mo.d_ff * mo.n_shared_experts)),
+                         "wo": on_chip((mo.d_ff * mo.n_shared_experts, d))}}
+    layer = jax.checkpoint(lambda p, x: moe_apply(p, cfg, x)[0])
+    grad = jax.grad(lambda p, x: jnp.sum(layer(p, x) ** 2), argnums=(0, 1))
+    lowered = jax.jit(grad).lower(params, on_chip((B, S, d)))
+    assert "stablehlo.case" in lowered.as_text()
+    text = lowered.compile().as_text()
+    comps = _hlo_computations(text)
+    conds = [line for line in text.splitlines() if " conditional(" in line]
+    assert len(conds) == 2, conds       # the forward and the backward pass
+    scopes = ("model.moe.dispatch", "model.moe.experts", "model.moe.combine")
+    for cond in conds:
+        group = re.search(r"branch_computations=\{([^}]*)\}", cond).group(1)
+        branches = [_hlo_closure(comps, n)
+                    for n in re.findall(r"%([\w.\-]+)", group)]
+        assert len(branches) == 2
+        # What a branch makes: its operands (parameters and the tuple
+        # elements read from them) are made outside it.
+        made = [[ln for ln in b
+                 if not re.search(r"\b(parameter|get-tuple-element)\(", ln)]
+                for b in branches]
+        full = [b for b in made if any(f"[{T * k}," in ln for ln in b)]
+        compact = [b for b in made if any(f"[{C}," in ln for ln in b)
+                   and not any(f"[{T * k}," in ln for ln in b)]
+        assert len(full) == 1 and len(compact) == 1
+        for branch in branches:
+            names = [n for n in re.findall(r'op_name="([^"]*)"',
+                                           "\n".join(branch))
+                     if "branch_" in n]
+            assert names and all(any(s in n for s in scopes) for n in names)
+            assert all(any(s in n for n in names) for s in scopes)
 
 
 def test_deepseek_share_round_fits_one_v5e(one_chip):
